@@ -244,51 +244,53 @@ class _FixedShapeUpdate:
         # assert on.  Under REPRO_OBS_STRICT=1 an open expect() window
         # turns an excess trace into a RecompileError right here.
         self.compiles.mark()
-        p = X.shape[1]
-        dt = jnp.promote_types(X.dtype, Y.dtype)
-        # One fused Xᵀ[X | Y] per slot — a single batched GEMM per chunk.
-        Z = jnp.concatenate([X.astype(dt), Y.astype(dt)], axis=1)
-        w = onehot                                          # (m, s) f32 0/1
-        if use_pallas:
-            # Kernel tier: mask + Gram + cross-covariance fused into one
-            # VMEM-resident blocked reduction — one HBM pass per chunk,
-            # the (s, m, p) masked intermediate never materialised.
-            from repro.kernels import ops
-            GC = ops.xty_folds_masked(X.astype(dt), Z,
-                                      w.astype(dt))         # (s, p, p+t)
-        else:
-            Xw = (X.astype(dt)[None]
-                  * jnp.swapaxes(w, 0, 1)[:, :, None].astype(dt))
-            GC = jnp.einsum("smp,mq->spq", Xw, Z, precision=PRECISION,
+        with jax.named_scope("chunk_update"):
+            p = X.shape[1]
+            dt = jnp.promote_types(X.dtype, Y.dtype)
+            # One fused Xᵀ[X | Y] per slot — one batched GEMM per chunk.
+            Z = jnp.concatenate([X.astype(dt), Y.astype(dt)], axis=1)
+            w = onehot                                      # (m, s) f32 0/1
+            if use_pallas:
+                # Kernel tier: mask + Gram + cross-covariance fused into one
+                # VMEM-resident blocked reduction — one HBM pass per chunk,
+                # the (s, m, p) masked intermediate never materialised.
+                from repro.kernels import ops
+                GC = ops.xty_folds_masked(X.astype(dt), Z,
+                                          w.astype(dt))         # (s, p, p+t)
+            else:
+                Xw = (X.astype(dt)[None]
+                      * jnp.swapaxes(w, 0, 1)[:, :, None].astype(dt))
+                GC = jnp.einsum("smp,mq->spq", Xw, Z, precision=PRECISION,
+                                preferred_element_type=jnp.float32)
+            Xf, Yf = X.astype(jnp.float32), Y.astype(jnp.float32)
+            cnt = jnp.sum(w, axis=0)                             # (s,)
+            xsum = jnp.einsum("ms,mp->sp", w, Xf, precision=PRECISION,
+                              preferred_element_type=jnp.float32)
+            ysum = jnp.einsum("ms,mt->st", w, Yf, precision=PRECISION,
+                              preferred_element_type=jnp.float32)
+            # Chan et al. pairwise combination of the centred second
+            # moment: M2_{a∪b} = M2_a + M2_b + (μ_a − μ_b)²·n_a n_b/(n_a+n_b)
+            # — exact, and free of the Σy² − mȳ² cancellation.  Per-slot
+            # quantities are gathered from / scattered back to each slot's
+            # fold; an empty slot has cnt = 0 so every one of its additions
+            # is exactly 0.
+            mu_b = ysum / jnp.maximum(cnt, 1.0)[:, None]
+            d = Yf[None, :, :] - mu_b[:, None, :]                # (s, m, t)
+            m2 = jnp.einsum("ms,smt->st", w, d * d, precision=PRECISION,
                             preferred_element_type=jnp.float32)
-        Xf, Yf = X.astype(jnp.float32), Y.astype(jnp.float32)
-        cnt = jnp.sum(w, axis=0)                             # (s,)
-        xsum = jnp.einsum("ms,mp->sp", w, Xf, precision=PRECISION,
-                          preferred_element_type=jnp.float32)
-        ysum = jnp.einsum("ms,mt->st", w, Yf, precision=PRECISION,
-                          preferred_element_type=jnp.float32)
-        # Chan et al. pairwise combination of the centred second moment:
-        # M2_{a∪b} = M2_a + M2_b + (μ_a − μ_b)²·n_a n_b/(n_a+n_b) — exact,
-        # and free of the Σy² − mȳ² cancellation.  Per-slot quantities are
-        # gathered from / scattered back to each slot's fold; an empty
-        # slot has cnt = 0 so every one of its additions is exactly 0.
-        mu_b = ysum / jnp.maximum(cnt, 1.0)[:, None]
-        d = Yf[None, :, :] - mu_b[:, None, :]                # (s, m, t)
-        m2 = jnp.einsum("ms,smt->st", w, d * d, precision=PRECISION,
-                        preferred_element_type=jnp.float32)
-        n_a = stats.count[slot_fold]                         # (s,)
-        mu_a = stats.ysum[slot_fold] / jnp.maximum(n_a, 1.0)[:, None]
-        both = ((n_a > 0) & (cnt > 0))[:, None]
-        delta2 = jnp.where(both, (mu_a - mu_b) ** 2, 0.0)
-        ysq_add = m2 + delta2 * (n_a * cnt
-                                 / jnp.maximum(n_a + cnt, 1.0))[:, None]
-        return FoldStats(
-            G=_add_slots(stats.G, slot_fold, GC[:, :, :p]),
-            C=_add_slots(stats.C, slot_fold, GC[:, :, p:]),
-            xsum=stats.xsum.at[slot_fold].add(xsum),
-            ysum=stats.ysum.at[slot_fold].add(ysum),
-            ysq=stats.ysq.at[slot_fold].add(ysq_add),
-            count=stats.count.at[slot_fold].add(cnt))
+            n_a = stats.count[slot_fold]                         # (s,)
+            mu_a = stats.ysum[slot_fold] / jnp.maximum(n_a, 1.0)[:, None]
+            both = ((n_a > 0) & (cnt > 0))[:, None]
+            delta2 = jnp.where(both, (mu_a - mu_b) ** 2, 0.0)
+            ysq_add = m2 + delta2 * (n_a * cnt
+                                     / jnp.maximum(n_a + cnt, 1.0))[:, None]
+            return FoldStats(
+                G=_add_slots(stats.G, slot_fold, GC[:, :, :p]),
+                C=_add_slots(stats.C, slot_fold, GC[:, :, p:]),
+                xsum=stats.xsum.at[slot_fold].add(xsum),
+                ysum=stats.ysum.at[slot_fold].add(ysum),
+                ysq=stats.ysq.at[slot_fold].add(ysq_add),
+                count=stats.count.at[slot_fold].add(cnt))
 
 
 # Module-level singleton: shards and repeated streams share one jit cache,
@@ -315,6 +317,24 @@ def chunk_update_compiles() -> "obs.CompileCounter":
     ``expect(at_most=...)`` window around a stream to arm the recompile
     sentinel (raises at trace time under ``REPRO_OBS_STRICT=1``)."""
     return _FIXED_UPDATE.compiles
+
+
+def send_chunk(put, Xs, Ys, onehot, slot_fold):
+    """``(put(Xs), put(Ys))`` inside one ``fit.chunk.h2d`` span.
+
+    The span's ``bytes`` (and the ``h2d_bytes{site=chunk}`` counter) are
+    the padded chunk as sent: its rows and targets, and the slot mask the
+    update's call carries.  While a tracer is installed the span waits
+    for the two copies, so it times the transfer; with none installed
+    nothing here waits.
+    """
+    nbytes = int(Xs.nbytes + Ys.nbytes + onehot.nbytes + slot_fold.nbytes)
+    with obs.transfer("fit.chunk.h2d", "h2d_bytes", nbytes, site="chunk",
+                      rows=int(Xs.shape[0])):
+        X, Y = put(Xs), put(Ys)
+        if obs.current() is not None:
+            jax.block_until_ready((X, Y))
+    return X, Y
 
 
 class FoldStatsAccumulator:
@@ -421,10 +441,9 @@ class FoldStatsAccumulator:
         splitting, padding, slot masks, offsets, and the finalize contract
         stay shared.
         """
-        self._stats = _FIXED_UPDATE(self._stats,
-                                    jax.device_put(Xs, self.device),
-                                    jax.device_put(Ys, self.device),
-                                    onehot, slot_fold,
+        X, Y = send_chunk(lambda a: jax.device_put(a, self.device),
+                          Xs, Ys, onehot, slot_fold)
+        self._stats = _FIXED_UPDATE(self._stats, X, Y, onehot, slot_fold,
                                     use_pallas=self.use_pallas)
 
     def update(self, X_chunk: jax.Array, Y_chunk: jax.Array) -> None:
@@ -445,10 +464,11 @@ class FoldStatsAccumulator:
             hi = min(lo + fixed, m)
             Xs, Ys = X_chunk[lo:hi], Y_chunk[lo:hi]
             if hi - lo < fixed:             # ragged: zero-pad to the shape
-                Xp = np.zeros((fixed, Xs.shape[1]), np.asarray(Xs).dtype)
-                Yp = np.zeros((fixed, Ys.shape[1]), np.asarray(Ys).dtype)
-                Xp[:hi - lo], Yp[:hi - lo] = Xs, Ys
-                Xs, Ys = Xp, Yp
+                with obs.span("fit.chunk.pad", rows=hi - lo):
+                    Xp = np.zeros((fixed, Xs.shape[1]), np.asarray(Xs).dtype)
+                    Yp = np.zeros((fixed, Ys.shape[1]), np.asarray(Ys).dtype)
+                    Xp[:hi - lo], Yp[:hi - lo] = Xs, Ys
+                    Xs, Ys = Xp, Yp
             onehot, slot_fold = self._slot_mask(hi - lo)
             self._apply(Xs, Ys, onehot, slot_fold)
             self._offset += hi - lo
@@ -461,7 +481,8 @@ class FoldStatsAccumulator:
         # the whole executable; chunk updates are sequentially dependent,
         # so no cross-chunk pipelining is lost, and the reader thread still
         # overlaps the next read with this compute.
-        jax.block_until_ready(self._stats.count)
+        with obs.span("fit.chunk.wait", rows=m):
+            jax.block_until_ready(self._stats.count)
 
     def finalize(self) -> FoldStats:
         if self._stats is None or self._offset != self.row_stop:

@@ -346,22 +346,25 @@ class ChunkPrefetcher:
             raise item
         self.stats.chunks += 1
         self._m_chunks.inc()
-        obs.instant("prefetch.yield", chunk=self.stats.chunks - 1)
         return item
 
     def close(self) -> None:
-        """Stop the reader, drain the queue, release the staging buffers."""
+        """Stop the reader, drain the queue, release the staging buffers
+        (one ``prefetch.close`` span; a repeated close does nothing)."""
         self._done = True
         self._stop.set()
-        while True:                     # unblock a reader stuck on put()
-            try:
-                self._queue.get_nowait()
-            except queue.Empty:
-                break
-        if self._thread is not None:
-            self._thread.join(timeout=5.0)
-            self._thread = None
-        self._bufs = None
+        if self._thread is None and self._bufs is None:
+            return
+        with obs.span("prefetch.close"):
+            while True:                 # unblock a reader stuck on put()
+                try:
+                    self._queue.get_nowait()
+                except queue.Empty:
+                    break
+            if self._thread is not None:
+                self._thread.join(timeout=5.0)
+                self._thread = None
+            self._bufs = None
 
     def __del__(self):
         try:

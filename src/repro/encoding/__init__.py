@@ -225,8 +225,8 @@ Three switches:
   time/bytes table and the root-coverage figure (the obs CI lane gates
   ≥95% of the fit root attributed to its phase children).
 * **Metrics**: ``obs.snapshot()`` renders the process-global counters
-  (``compiles{tier=...}``, ``bytes_staged``, ``waves``,
-  ``tenant_rows{tenant=...}``, ``registry_hits``/``loads``/
+  (``compiles{tier=...}``, ``bytes_staged``, ``h2d_bytes{site=...}``,
+  ``d2h_bytes{site=wave}``, ``registry_hits``/``loads``/
   ``evictions``, fleet admission outcomes) plus the RSS high-water gauge
   into one schema'd dict (``repro.obs/v1``); ``--metrics-out PATH``
   writes it on launcher exit.  ``stream_stats_``,

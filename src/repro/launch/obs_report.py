@@ -2,8 +2,10 @@
 
 Reads the JSONL span stream the launchers emit under ``--trace-out``
 (one ``repro.obs`` event per line) and prints, per span name: call
-count, total seconds, mean milliseconds, share of the trace wall, and
-the bytes the spans carried (``bytes``/``bytes_staged`` attrs).
+count, total seconds, self seconds (the total less the time of each
+span's direct children, matched by ``parent_id``), mean milliseconds,
+share of the trace wall, and the bytes the spans carried
+(``bytes``/``bytes_staged`` attrs).
 
 The report also computes **root coverage**: the fraction of the longest
 root (depth-0) span's wall time attributed to its direct (depth-1)
@@ -45,16 +47,30 @@ def _span_bytes(ev: dict) -> int:
         + int(attrs.get("bytes_staged", 0) or 0)
 
 
+def _child_us(events: list[dict]) -> dict:
+    """Time of each span's direct children, by the parent's ``id``."""
+    child: dict = {}
+    for ev in events:
+        pid = ev.get("parent_id")
+        if pid is not None and not ev.get("instant"):
+            child[pid] = child.get(pid, 0.0) + ev["dur_us"]
+    return child
+
+
 def summarize(events: list[dict]) -> dict:
-    """Aggregate per span name: ``{name: {count, total_us, bytes}}``."""
+    """Aggregate per span name: ``{name: {count, total_us, self_us,
+    bytes}}``.  ``self_us`` is the total less each span's direct
+    children; a trace without span ids attributes no child time."""
+    child = _child_us(events)
     agg: dict[str, dict] = {}
     for ev in events:
         if ev.get("instant"):
             continue
-        row = agg.setdefault(ev["name"],
-                             {"count": 0, "total_us": 0.0, "bytes": 0})
+        row = agg.setdefault(ev["name"], {"count": 0, "total_us": 0.0,
+                                          "self_us": 0.0, "bytes": 0})
         row["count"] += 1
         row["total_us"] += ev["dur_us"]
+        row["self_us"] += ev["dur_us"] - child.get(ev.get("id"), 0.0)
         row["bytes"] += _span_bytes(ev)
     return agg
 
@@ -69,6 +85,9 @@ def root_coverage(events: list[dict]) -> tuple[dict | None, float]:
     root = max(roots, key=lambda e: e["dur_us"])
     if root["dur_us"] <= 0:
         return root, 0.0
+    if root.get("id") is not None:
+        return root, _child_us(events).get(root["id"], 0.0) / root["dur_us"]
+    # A trace without span ids: children by name, inside the root's time.
     lo, hi = root["ts_us"], root["ts_us"] + root["dur_us"]
     kids = [e for e in events
             if e.get("depth") == 1 and not e.get("instant")
@@ -84,7 +103,7 @@ def render(events: list[dict]) -> str:
     wall_us = (max(e["ts_us"] + e.get("dur_us", 0.0) for e in events)
                - min(e["ts_us"] for e in events)) or 1.0
     name_w = max(len(n) for n in agg) + 2
-    lines = [f"{'span':<{name_w}}{'count':>7}{'total_s':>10}"
+    lines = [f"{'span':<{name_w}}{'count':>7}{'total_s':>10}{'self_s':>10}"
              f"{'mean_ms':>10}{'%wall':>8}{'MB':>10}"]
     for name, row in sorted(agg.items(),
                             key=lambda kv: -kv[1]["total_us"]):
@@ -92,6 +111,7 @@ def render(events: list[dict]) -> str:
         mean_ms = row["total_us"] / row["count"] / 1e3
         lines.append(
             f"{name:<{name_w}}{row['count']:>7}{total_s:>10.3f}"
+            f"{row['self_us'] / 1e6:>10.3f}"
             f"{mean_ms:>10.2f}{100 * row['total_us'] / wall_us:>7.1f}%"
             f"{row['bytes'] / 2**20:>10.2f}")
     n_instants = sum(1 for e in events if e.get("instant"))

@@ -13,8 +13,9 @@ all emit through the SAME three primitives instead of bespoke stat dicts:
   attribute load — hot paths stay permanently instrumented.
   ``obs.timed`` additionally ALWAYS measures (the streaming tier derives
   ``PrefetchStats`` stall seconds from the same measurement the span
-  records).  ``obs.instant`` records zero-duration markers
-  (admit/reject/hit).
+  records); ``obs.transfer`` always counts a copy's bytes into a
+  registry counter and records the same number on its span.
+  ``obs.instant`` records zero-duration markers (reject/hit/evict).
 * **Metrics** — ``obs.get_metrics()`` returns the process-global
   :class:`~repro.obs.metrics.MetricsRegistry`; ``obs.snapshot()`` renders
   every counter/gauge/histogram into one JSON dict (schema below) that
@@ -30,10 +31,19 @@ all emit through the SAME three primitives instead of bespoke stat dicts:
 
 Span naming convention: dotted ``<tier>.<phase>[.<subphase>]`` —
 ``fit.dispatch`` / ``fit.stats`` / ``fit.eigh`` / ``fit.solve``,
-``prefetch.stage`` / ``prefetch.wait`` / ``prefetch.compute_stall``,
+``fit.chunk.pad`` (a ragged chunk's zero-pad) / ``fit.chunk.h2d`` /
+``fit.chunk.wait`` (each streamed chunk),
+``fit.cv.solve`` / ``fit.cv.score`` (each fold, inside ``fit.eigh``,
+whose ``method`` attribute says which factorisation ran),
+``prefetch.stage`` / ``prefetch.wait`` / ``prefetch.compute_stall`` /
+``prefetch.close``,
 ``wholebrain.block`` / ``wholebrain.xstats``, ``serve.wave.build`` /
-``serve.wave.execute``, ``registry.load`` / ``registry.evict`` /
-``registry.hit``, ``fleet.admit`` / ``fleet.reject`` / ``fleet.flush``.
+``serve.wave.h2d`` / ``serve.wave.execute`` / ``serve.wave.sums_wait`` /
+``serve.copyback`` (each carrying its ``wave`` number) and
+``serve.results``, ``registry.load`` / ``registry.evict`` /
+``registry.hit``, ``fleet.reject`` / ``fleet.flush`` (with its ``flush``
+number).  A span that times device work forces its outputs only while a
+tracer is installed, so tracing off leaves the schedule untouched.
 
 Metrics-snapshot schema (``obs.snapshot()``; version ``repro.obs/v1``)
 ----------------------------------------------------------------------
@@ -62,9 +72,16 @@ instrument                            type       producer
 ``chunks_staged``                     counter    prefetcher chunks staged
 ``read_stall_s`` / ``compute_stall_s``  counter  prefetcher stall seconds (consumer /
                                                  producer side)
-``wave_pad_rows`` / ``wave_rows``     counter    serving pad vs real rows per wave
-``waves``                             counter    compiled predict waves executed
-``tenant_rows{tenant=...}``           counter    per-tenant served rows
+``h2d_bytes{site=...}``               counter    bytes sent host → device (sites:
+                                                 ``chunk``, a streamed chunk's
+                                                 rows, targets and slot mask;
+                                                 ``wave``, a serving wave's
+                                                 inputs); also the ``bytes`` of
+                                                 ``fit.chunk.h2d`` /
+                                                 ``serve.wave.h2d``
+``d2h_bytes{site=wave}``              counter    prediction bytes copied back
+                                                 per wave; also the ``bytes``
+                                                 of ``serve.copyback``
 ``registry_hits`` / ``registry_loads``  counter  bundle cache hits / cold loads
 ``registry_evictions``                counter    LRU + fault evictions
 ``admitted_rows`` / ``rejected_requests``  counter  fleet admission outcomes
@@ -88,13 +105,14 @@ instrument                            type       producer
 Stats ``to_dict()`` payloads (``PrefetchStats``, ``ServiceStats``, and the
 ``stream_stats_`` dict) carry ``{"schema": "repro.obs/v1", "kind": ...}``
 plus their flat snake_case fields — benches consume those dicts, never
-raw attributes.
+raw attributes.  ``ServiceStats`` is the one account of waves, pad rows
+and tenants; the registry holds no copy of it.
 
 Surfacing: ``launch/encode.py``, ``launch/wholebrain.py`` and
 ``launch/serve.py`` accept ``--trace-out PATH`` (``.json`` → Perfetto,
 else JSONL) and ``--metrics-out PATH``; ``launch/obs_report.py`` renders
-a per-phase time/bytes table from a JSONL trace and can gate span
-coverage (``--assert-coverage``).
+a per-phase time/self-time/bytes table from a JSONL trace and can gate
+span coverage (``--assert-coverage``).
 """
 from repro.obs.metrics import (  # noqa: F401
     REGISTRY, SCHEMA_VERSION, Counter, Gauge, Histogram, MetricsRegistry,
@@ -104,12 +122,13 @@ from repro.obs.sentinel import (  # noqa: F401
     CompileCounter, RecompileError, strict_enabled,
 )
 from repro.obs.trace import (  # noqa: F401
-    Tracer, current, install, instant, span, timed, uninstall, write_trace,
+    Tracer, current, install, instant, span, timed, transfer, uninstall,
+    write_trace,
 )
 
 __all__ = [
-    "Tracer", "span", "timed", "instant", "install", "uninstall", "current",
-    "write_trace",
+    "Tracer", "span", "timed", "transfer", "instant", "install",
+    "uninstall", "current", "write_trace",
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "REGISTRY",
     "get_metrics", "snapshot", "start_rss_poller", "read_rss_bytes",
     "SCHEMA_VERSION",

@@ -15,8 +15,11 @@ records a completed event on exit:
 * ``track`` — a small per-thread integer (0 = first thread seen), and
   ``tid`` the OS thread ident, so concurrent threads render as separate
   tracks in Perfetto.
-* ``depth``/``parent`` — nesting within the thread (a thread-local span
-  stack), so reports can attribute child time to phases.
+* ``id``/``parent_id``/``parent``/``depth`` — every event's integer id
+  (unique within the tracer), and the id and name of the span that
+  encloses it on its thread (a thread-local span stack), so reports can
+  attribute child time to its own parent even where a name repeats
+  (self time, the spans of one flush).
 * ``attrs`` — user key/values (``bytes=...``, ``tenant=...``).
 
 Export formats:
@@ -35,15 +38,18 @@ SAME measurement instead of parallel bookkeeping.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 from typing import Any
 
+from repro.obs.metrics import get_metrics
+
 __all__ = [
-    "Tracer", "span", "timed", "instant", "install", "uninstall",
-    "current", "write_trace",
+    "Tracer", "span", "timed", "transfer", "instant", "install",
+    "uninstall", "current", "write_trace",
 ]
 
 _tracer: "Tracer | None" = None
@@ -68,13 +74,14 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "id")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self._t0 = 0.0
+        self.id = next(tracer._ids)
 
     def set(self, **attrs):
         """Attach/override attributes mid-span."""
@@ -95,9 +102,8 @@ class _Span:
         tr = self._tracer
         stack = tr._tls.stack
         stack.pop()
-        parent = stack[-1].name if stack else None
-        tr._record(self.name, self._t0, t1 - self._t0, len(stack),
-                   parent, self.attrs)
+        tr._record(self.name, self._t0, t1 - self._t0, stack, self.attrs,
+                   self.id)
         return False
 
 
@@ -129,11 +135,8 @@ class _Timed:
         self.dur_s = t1 - self._t0
         tr = _tracer
         if tr is not None:
-            stack = getattr(tr._tls, "stack", None)
-            depth = len(stack) if stack else 0
-            parent = stack[-1].name if stack else None
-            tr._record(self.name, self._t0, self.dur_s, depth, parent,
-                       self.attrs)
+            tr._record(self.name, self._t0, self.dur_s,
+                       getattr(tr._tls, "stack", None), self.attrs)
         return False
 
 
@@ -145,6 +148,7 @@ class Tracer:
         self._events: list[dict] = []
         self._tls = threading.local()
         self._tracks: dict[int, int] = {}
+        self._ids = itertools.count()   # _Span takes its id outside the lock
         self._epoch = time.perf_counter()
         self.pid = os.getpid()
 
@@ -158,37 +162,33 @@ class Tracer:
             tid = self._tracks[ident] = len(self._tracks)
         return tid
 
-    def _record(self, name: str, t0: float, dur_s: float, depth: int,
-                parent: str | None, attrs: dict) -> None:
+    def _record(self, name: str, t0: float, dur_s: float,
+                stack: list | None, attrs: dict, span_id: int | None = None,
+                instant: bool = False) -> None:
+        """Append one event; ``stack`` is the thread's open spans, whose
+        innermost is the event's parent."""
         ident = threading.get_ident()
         with self._lock:
-            self._events.append({
+            ev = {
                 "name": name,
+                "id": next(self._ids) if span_id is None else span_id,
                 "ts_us": round((t0 - self._epoch) * 1e6, 3),
                 "dur_us": round(dur_s * 1e6, 3),
                 "track": self._track_id(ident),
                 "tid": ident,
-                "depth": depth,
-                "parent": parent,
+                "depth": len(stack) if stack else 0,
+                "parent": stack[-1].name if stack else None,
+                "parent_id": stack[-1].id if stack else None,
                 "attrs": attrs,
-            })
+            }
+            if instant:
+                ev["instant"] = True
+            self._events.append(ev)
 
     def instant(self, name: str, **attrs: Any) -> None:
         """Record a zero-duration marker event (Perfetto ``ph="i"``)."""
-        ident = threading.get_ident()
-        stack = getattr(self._tls, "stack", None)
-        with self._lock:
-            self._events.append({
-                "name": name,
-                "ts_us": round((time.perf_counter() - self._epoch) * 1e6, 3),
-                "dur_us": 0.0,
-                "track": self._track_id(ident),
-                "tid": ident,
-                "depth": len(stack) if stack else 0,
-                "parent": stack[-1].name if stack else None,
-                "attrs": attrs,
-                "instant": True,
-            })
+        self._record(name, time.perf_counter(), 0.0,
+                     getattr(self._tls, "stack", None), attrs, instant=True)
 
     # -- reading / export --------------------------------------------------
     def events(self) -> list[dict]:
@@ -208,7 +208,8 @@ class Tracer:
             rec = {"name": ev["name"], "cat": ev["name"].split(".")[0],
                    "ph": "i" if ev.get("instant") else "X",
                    "ts": ev["ts_us"], "pid": self.pid, "tid": ev["track"],
-                   "args": dict(ev["attrs"], depth=ev["depth"])}
+                   "args": dict(ev["attrs"], depth=ev["depth"], id=ev["id"],
+                                parent_id=ev["parent_id"])}
             if not ev.get("instant"):
                 rec["dur"] = ev["dur_us"]
             else:
@@ -256,8 +257,19 @@ def timed(name: str, **attrs: Any) -> _Timed:
     return _Timed(name, attrs)
 
 
+def transfer(name: str, counter: str, nbytes: int, *, site: str,
+             **attrs: Any):
+    """A span over a copy between host and device: ``nbytes`` is added
+    to the registry counter ``counter{site=...}`` whether or not a tracer
+    is installed, and recorded as the span's ``bytes`` attribute — one
+    count, two views (``--metrics-out`` and the trace).  The caller
+    forces the copy inside the span only while a tracer is installed."""
+    get_metrics().counter(counter, site=site).inc(nbytes)
+    return span(name, bytes=nbytes, **attrs)
+
+
 def instant(name: str, **attrs: Any) -> None:
-    """Zero-duration marker (admit/reject/hit events)."""
+    """Zero-duration marker (reject/hit/evict events)."""
     t = _tracer
     if t is not None:
         t.instant(name, **attrs)

@@ -336,6 +336,7 @@ class FleetFrontend:
         self.admitted = 0
         self.rejected = 0
         self.replayed = 0    # requests re-admitted after a lost worker
+        self.flushes = 0     # numbers each flush's ``fleet.flush`` span
 
     @property
     def pending_rows(self) -> int:
@@ -361,7 +362,6 @@ class FleetFrontend:
         self._pending_rows += rows
         self.admitted += 1
         obs.get_metrics().counter("admitted_rows").inc(rows)
-        obs.instant("fleet.admit", tenant=request.tenant_id, rows=rows)
         return idx
 
     def flush(self, *, wave_rows: int | None = None) -> list[PredictResult]:
@@ -380,7 +380,9 @@ class FleetFrontend:
         rows = self._pending_rows
         self._pending = []
         self._pending_rows = 0
-        with obs.span("fleet.flush", requests=len(batch), rows=rows):
+        self.flushes += 1
+        with obs.span("fleet.flush", requests=len(batch), rows=rows,
+                      flush=self.flushes - 1):
             try:
                 return self.service.serve(batch, wave_rows=wave_rows)
             except WorkerLost:

@@ -123,10 +123,6 @@ class ServiceStats:
         b["pad_rows"] += wave_rows - real
         self.waves += 1
         self.pad_rows += wave_rows - real
-        m = obs.get_metrics()
-        m.counter("waves", bucket=wave_rows).inc()
-        m.counter("wave_rows").inc(real)
-        m.counter("wave_pad_rows").inc(wave_rows - real)
 
     def tenant(self, tenant: str) -> dict:
         return self.per_tenant.setdefault(
@@ -140,7 +136,6 @@ class ServiceStats:
         acct["bytes"] += nbytes
         acct["requests"] += 1
         acct["scored"] += int(scored)
-        obs.get_metrics().counter("tenant_rows", tenant=tenant).inc(rows)
 
     def record_error(self, tenant: str) -> None:
         self.tenant(tenant)["errors"] += 1
@@ -282,6 +277,7 @@ class EncoderService:
         self.return_predictions = return_predictions
         self.compiles = obs.CompileCounter("service.predict")
         self._seen_shapes: set = set()
+        self._waves_flown = 0        # numbers each mixed wave's spans
         self.stats = ServiceStats()
 
         def _predict(X, W, mu_x, sd_x, mu_y, sd_y):
@@ -289,10 +285,11 @@ class EncoderService:
             # (wave shape, weight shape/dtype/sharding) signature — the
             # compile counter the serving bench/CI lane asserts on.
             self.compiles.mark()
-            Xs = (X - mu_x) / sd_x
-            P = jnp.matmul(Xs, W, precision=_PRECISION,
-                           preferred_element_type=jnp.float32)
-            return P * sd_y + mu_y
+            with jax.named_scope("wave_matmul"):
+                Xs = (X - mu_x) / sd_x
+                P = jnp.matmul(Xs, W, precision=_PRECISION,
+                               preferred_element_type=jnp.float32)
+                return P * sd_y + mu_y
 
         def _predict_mixed(X, Yt, onehot, sums_in, W, mu_x, sd_x,
                            mu_y, sd_y):
@@ -303,16 +300,14 @@ class EncoderService:
             # ±0, so a request's sums are bit-identical at any wave
             # offset/cut to serving it alone — the replay-harness gate.
             self.compiles.mark()
-            Xs = (X - mu_x) / sd_x
-            P = jnp.matmul(Xs, W, precision=_PRECISION,
-                           preferred_element_type=jnp.float32)
-            P = P * sd_y + mu_y
+            with jax.named_scope("wave_matmul"):
+                Xs = (X - mu_x) / sd_x
+                P = jnp.matmul(Xs, W, precision=_PRECISION,
+                               preferred_element_type=jnp.float32)
+                P = P * sd_y + mu_y
             m = X.shape[0]
             m8 = -(-m // 8) * 8
             pad = ((0, m8 - m), (0, 0))
-            Yp = jnp.pad(Yt, pad)
-            Pp = jnp.pad(P, pad)
-            wp = jnp.pad(onehot, pad)               # pad rows: weight 0
 
             def step(sums, blk):
                 y8, p8, w8 = blk                    # (8, t) (8, t) (8, s)
@@ -322,11 +317,14 @@ class EncoderService:
                     sums = sums + w[:, None, None] * terms[None]
                 return sums, None
 
-            import jax as _jax
-            sums_out, _ = _jax.lax.scan(
-                step, sums_in,
-                (Yp.reshape(m8 // 8, 8, -1), Pp.reshape(m8 // 8, 8, -1),
-                 wp.reshape(m8 // 8, 8, -1)))
+            with jax.named_scope("wave_scan"):
+                Yp = jnp.pad(Yt, pad)
+                Pp = jnp.pad(P, pad)
+                wp = jnp.pad(onehot, pad)           # pad rows: weight 0
+                sums_out, _ = jax.lax.scan(
+                    step, sums_in,
+                    (Yp.reshape(m8 // 8, 8, -1), Pp.reshape(m8 // 8, 8, -1),
+                     wp.reshape(m8 // 8, 8, -1)))
             return P, sums_out
 
         self._predict = jax.jit(_predict)
@@ -455,6 +453,7 @@ class EncoderService:
                      blocks: list[np.ndarray], t: int, max_wave: int,
                      wave_rows: int | None) -> list[PredictResult]:
         """Fly one model's packed mixed waves; results in ``reqs`` order."""
+        import jax
         import jax.numpy as jnp
 
         from repro.kernels import ops
@@ -476,10 +475,13 @@ class EncoderService:
         req_sums = {j: np.zeros((5, t), np.float32)
                     for j, sc in enumerate(scored) if sc}
         p = blocks[0].shape[1]
-        flown: list[tuple[MixedWave, object]] = []
+        traced = obs.current() is not None
+        flown: list[tuple[MixedWave, object, int]] = []
         for wave in plan:
+            seq = self._waves_flown
+            self._waves_flown += 1
             with obs.span("serve.wave.build", rows=wave.rows,
-                          fill=wave.fill, model=model):
+                          fill=wave.fill, model=model, wave=seq):
                 X = np.zeros((wave.rows, p), np.float32)
                 Yt = np.zeros((wave.rows, t), np.float32)
                 onehot = np.zeros((wave.rows, s), np.float32)
@@ -494,50 +496,63 @@ class EncoderService:
                         Yt[dst] = targets[seg.req][seg.req_lo:seg.req_hi]
                         onehot[dst, seg.slot] = 1.0
                         sums_in[seg.slot] = req_sums[seg.req]
+            # Under a tracer each span below waits for its device work, so
+            # it times the copy or the program rather than its enqueue.
+            nbytes = X.nbytes + Yt.nbytes + onehot.nbytes + sums_in.nbytes
+            with obs.transfer("serve.wave.h2d", "h2d_bytes", nbytes,
+                              site="wave", rows=wave.rows, fill=wave.fill,
+                              wave=seq):
+                inputs = (jnp.asarray(X), jnp.asarray(Yt),
+                          jnp.asarray(onehot), jnp.asarray(sums_in))
+                if traced:
+                    jax.block_until_ready(inputs)
             with obs.span("serve.wave.execute", rows=wave.rows,
-                          fill=wave.fill, model=model):
+                          fill=wave.fill, model=model, wave=seq):
                 with self._expect_shape(("mixed", wave.rows, p, t, s)):
-                    P, sums_out = self._predict_mixed(
-                        jnp.asarray(X), jnp.asarray(Yt), jnp.asarray(onehot),
-                        jnp.asarray(sums_in), *enc_args)
+                    P, sums_out = self._predict_mixed(*inputs, *enc_args)
                 self.stats.record_wave(wave.rows, wave.fill)
-                if has_scored:
-                    # The chain is a data dependency: the slot carries must
-                    # land on host before the request's NEXT wave is built.
-                    # Unscored waves stay fully async-enqueued.
+            if has_scored:
+                # The chain is a data dependency: the slot carries must
+                # land on host before the request's NEXT wave is built.
+                # Unscored waves stay fully async-enqueued.
+                with obs.span("serve.wave.sums_wait", rows=wave.rows,
+                              wave=seq):
                     host_sums = np.asarray(sums_out)
-                    for seg in wave.segments:
-                        if seg.slot is not None:
-                            req_sums[seg.req] = host_sums[seg.slot]
-            flown.append((wave, P))
+                for seg in wave.segments:
+                    if seg.slot is not None:
+                        req_sums[seg.req] = host_sums[seg.slot]
+            flown.append((wave, P, seq))
 
         out_pred = None
         if self.return_predictions:
             out_pred = {j: np.empty((b.shape[0], t), np.float32)
                         for j, b in enumerate(blocks)}
-            for wave, P in flown:
-                host = np.asarray(P)
-                for seg in wave.segments:
-                    out_pred[seg.req][seg.req_lo:seg.req_hi] = \
-                        host[seg.wave_lo:seg.wave_lo + seg.req_hi - seg.req_lo]
+            for wave, P, seq in flown:
+                with obs.transfer("serve.copyback", "d2h_bytes", P.nbytes,
+                                  site="wave", rows=wave.rows, wave=seq):
+                    host = np.asarray(P)
+                    for seg in wave.segments:
+                        out_pred[seg.req][seg.req_lo:seg.req_hi] = host[
+                            seg.wave_lo:seg.wave_lo + seg.req_hi - seg.req_lo]
 
-        results = []
-        for j, req in enumerate(reqs):
-            r = None
-            if scored[j]:
-                # Finalise from the accumulated chain with the kernel's
-                # formula — identical sums (packed vs alone) → identical r.
-                r = np.asarray(ops.pearson_r_from_sums(
-                    req_sums[j].astype(np.float64), blocks[j].shape[0]))
-            results.append(PredictResult(
-                model=model,
-                predictions=None if out_pred is None else out_pred[j],
-                pearson_r=r))
-            self.stats.rows += blocks[j].shape[0]
-            self.stats.record_request(
-                req.tenant_id, blocks[j].shape[0],
-                blocks[j].nbytes + (targets[j].nbytes if scored[j] else 0),
-                scored[j])
+        with obs.span("serve.results", requests=len(reqs)):
+            results = []
+            for j, req in enumerate(reqs):
+                r = None
+                if scored[j]:
+                    # Finalise from the accumulated chain with the kernel's
+                    # formula: identical sums (packed vs alone) → same r.
+                    r = np.asarray(ops.pearson_r_from_sums(
+                        req_sums[j].astype(np.float64), blocks[j].shape[0]))
+                results.append(PredictResult(
+                    model=model,
+                    predictions=None if out_pred is None else out_pred[j],
+                    pearson_r=r))
+                self.stats.rows += blocks[j].shape[0]
+                self.stats.record_request(
+                    req.tenant_id, blocks[j].shape[0],
+                    blocks[j].nbytes
+                    + (targets[j].nbytes if scored[j] else 0), scored[j])
         return results
 
     def _prefetch(self, model: str, max_wave: int) -> None:

@@ -218,8 +218,8 @@ class ColumnBlockAccumulator(foldstats.FoldStatsAccumulator):
             Yp = np.zeros((Ys.shape[0], self.t_pad), Ys.dtype)
             Yp[:, :Ys.shape[1]] = Ys
             Ys = Yp
-        self._stats = _COLBLOCK_UPDATE(self._stats, jnp.asarray(Xs),
-                                       jnp.asarray(Ys), onehot, slot_fold,
+        X, Y = foldstats.send_chunk(jnp.asarray, Xs, Ys, onehot, slot_fold)
+        self._stats = _COLBLOCK_UPDATE(self._stats, X, Y, onehot, slot_fold,
                                        use_pallas=self.use_pallas)
 
 

@@ -115,9 +115,30 @@ def test_span_thread_safety_distinct_tracks():
             assert e["depth"] == 1
 
 
-# ---------------------------------------------------------------------------
-# exports
-# ---------------------------------------------------------------------------
+def test_span_ids_identify_parents_when_names_repeat():
+    tracer = obs.install()
+    for _ in range(2):
+        with obs.span("flush"):
+            with obs.span("wave"):
+                with obs.timed("wait"):
+                    pass
+            obs.instant("mark")
+    obs.uninstall()
+
+    ev = tracer.events()
+    assert len({e["id"] for e in ev}) == len(ev) == 8
+    flushes = [e for e in ev if e["name"] == "flush"]
+    waves = [e for e in ev if e["name"] == "wave"]
+    assert [f["parent_id"] for f in flushes] == [None, None]
+    for f, w in zip(flushes, waves):
+        kids = [e for e in ev if e["parent_id"] == f["id"]]
+        assert sorted(k["name"] for k in kids) == ["mark", "wave"]
+        (wait,) = [e for e in ev if e["parent_id"] == w["id"]]
+        assert wait["name"] == "wait" and wait["parent"] == "wave"
+    for rec in tracer.to_perfetto()["traceEvents"]:
+        assert {"id", "parent_id"} <= set(rec["args"])
+
+
 
 def _sample_tracer():
     tracer = obs.install()
@@ -172,7 +193,32 @@ def test_obs_report_coverage_and_render(tmp_path):
     assert root["name"] == "root"
     assert cov > 0.9                      # sleeps dominate the root
     out = obs_report.render(events)
-    assert "root" in out and "%wall" in out
+    assert "root" in out and "%wall" in out and "self_s" in out
+
+
+def test_obs_report_self_time_by_span_id():
+    events = [
+        {"name": "flush", "id": 0, "parent_id": None, "ts_us": 0.0,
+         "dur_us": 100.0, "depth": 0},
+        {"name": "wave", "id": 1, "parent_id": 0, "ts_us": 0.0,
+         "dur_us": 30.0, "depth": 1},
+        {"name": "wave", "id": 2, "parent_id": 0, "ts_us": 40.0,
+         "dur_us": 50.0, "depth": 1},
+        {"name": "wait", "id": 3, "parent_id": 2, "ts_us": 50.0,
+         "dur_us": 20.0, "depth": 2},
+        {"name": "mark", "id": 4, "parent_id": 2, "ts_us": 60.0,
+         "dur_us": 0.0, "depth": 2, "instant": True},
+    ]
+    agg = obs_report.summarize(events)
+    assert agg["flush"]["self_us"] == 20.0
+    assert agg["wave"]["self_us"] == 60.0
+    assert agg["wait"]["self_us"] == 20.0
+    root, cov = obs_report.root_coverage(events)
+    assert root["id"] == 0 and cov == pytest.approx(0.8)
+    # A trace without ids: no child time attributed to self time.
+    bare = [{k: v for k, v in e.items() if k not in ("id", "parent_id")}
+            for e in events]
+    assert obs_report.summarize(bare)["flush"]["self_us"] == 100.0
 
 
 def test_parse_sweep_log_accepts_obs_traces(tmp_path):

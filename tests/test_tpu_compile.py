@@ -121,3 +121,22 @@ def test_chunk_update_fits_one_chip(one_chip, monkeypatch):
     # the plan holds one copy of (k, p, p+t), not two.
     assert mem.alias_size_in_bytes >= K * P * (P + T) * 4
     assert planned < V5E_BYTES_LIMIT, planned
+
+
+def test_cv_scoring_program_fits_one_chip(one_chip):
+    """The per-λ path's held-out scoring, one program at p = 16,384 over
+    the whole fold statistics, plans fewer bytes than one chip may
+    allocate, and its ops keep their scope for the device trace."""
+    from repro.core import foldstats, ridge
+    stats = foldstats.FoldStats(
+        G=_spec((K, P, P), one_chip), C=_spec((K, P, T), one_chip),
+        xsum=_spec((K, P), one_chip), ysum=_spec((K, T), one_chip),
+        ysq=_spec((K, T), one_chip), count=_spec((K,), one_chip))
+    compiled = ridge._cv_score.lower(
+        stats, _spec((), one_chip, jnp.int32), _spec((R, P, T), one_chip),
+        "r2").compile()
+    mem = compiled.memory_analysis()
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert planned < V5E_BYTES_LIMIT, planned
+    assert "/cv_score/" in compiled.as_text()
