@@ -469,9 +469,13 @@ def _cv_from_stats(stats: "foldstats.FoldStats", cfg: RidgeCVConfig, *,
     # outer span keeps the name ``fit.eigh`` on both paths (a benchmark
     # metric reads it); ``method`` says which factorisation ran.
     # eye/λ-grid construction lives inside the span: their first-touch
-    # dispatch cost belongs to the factorisation phase it feeds.
-    with obs.span("fit.eigh", folds=stats.n_folds, p=p,
-                  method="per_lambda_cholesky" if per_lambda else "eigh"):
+    # dispatch cost belongs to the factorisation phase it feeds.  On the
+    # per-λ path the span also says which band plan ran, from the shape.
+    attrs = ({"method": "per_lambda_cholesky",
+              "bands": len(spd.band_plan(p)),
+              "factor_update_flops": spd.update_flops(p)}
+             if per_lambda else {"method": "eigh"})
+    with obs.span("fit.eigh", folds=stats.n_folds, p=p, **attrs):
         eye = cfg.jitter * jnp.eye(p, dtype=jnp.float32)
         lams = _lambda_grid(cfg)
         for f in range(stats.n_folds):

@@ -3,23 +3,32 @@
 ``(G + λI) W = C`` for a symmetric positive definite ``G`` (p × p), as a
 left-looking blocked Cholesky and two blocked triangular sweeps, each a
 ``lax.fori_loop`` over ``p / block`` column blocks.  The loop body is one
-fixed-shape program (a masked ``(p, p) @ (p, block)`` update, a
-``block × block`` Cholesky and triangular solve), so the compiled code is
-the same size at any p.
+fixed set of programs (a banded update, a ``block × block`` Cholesky and
+triangular solve), so the compiled code is the same size at any p.
 
 This is the TPU's factorisation for the streamed CV.  There, XLA expands
 ``cholesky``/``eigh`` into code that grows with p: compiled for v5e at
 p = 16,384 the plain Cholesky solve is 417 MB of code and 212 s to
 compile, the QDWH eigh does not finish compiling, and the Jacobi eigh
-custom call takes 2.7 s at p = 2,048 and grows as p³.  The masked
-full-height update costs 2p³ FLOPs per factorisation (a dense one needs
-p³/3): the price of one shape per step.
+custom call takes 2.7 s at p = 2,048 and grows as p³.
+
+The update of step k needs only the rows at or below ``lo = k·block`` and
+the finished columns before ``lo``.  The steps are split into
+``min(BANDS, p / block)`` consecutive bands (``band_plan``); each band
+reads one static rectangle of the factor, from its first step's ``lo``
+down and up to its last step's ``lo`` across, so each band is one fixed
+shape.  A factorisation costs ``update_flops(p)`` for its updates: at
+p = 16,384 and a 512 block, 1.91e12 FLOPs, 1.3 times a dense one's p³/3
+(a full-height update at every step would cost 2p³, 8.80e12).
 
 Every matmul runs at the fit's precision, ``kernels.gram.PRECISION``.
 """
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +36,37 @@ import jax.numpy as jnp
 from repro.kernels.gram import PRECISION
 
 BLOCK = 512
+BANDS = 8          # static bands of the Cholesky update (``band_plan``)
+
+
+class Band(NamedTuple):
+    """Steps ``first`` to ``stop − 1`` of the left-looking Cholesky, whose
+    update reads the factor's rows from ``row0`` down and its columns
+    before ``col_end``."""
+    first: int
+    stop: int
+    row0: int      # the first step's lo
+    col_end: int   # the last step's lo
+
+
+def band_plan(p: int, block: int = BLOCK) -> tuple[Band, ...]:
+    """The bands of a factorisation of a p × p matrix (p padded up to a
+    multiple of ``block``): ``min(BANDS, nb)`` runs of consecutive steps,
+    whose lengths differ by at most one."""
+    nb = -(-p // block)
+    s = min(BANDS, nb)
+    cuts = [nb * i // s for i in range(s + 1)]
+    return tuple(Band(a, b, a * block, (b - 1) * block)
+                 for a, b in zip(cuts, cuts[1:]))
+
+
+def update_flops(p: int, block: int = BLOCK) -> int:
+    """FLOPs of the updates of one factorisation, as ``cholesky`` runs
+    them: each step multiplies its band's (q − row0, col_end) rectangle
+    by a (col_end, block) row block, q being p padded."""
+    q = -(-p // block) * block
+    return sum((b.stop - b.first) * 2 * (q - b.row0) * b.col_end * block
+               for b in band_plan(p, block))
 
 
 def _dot(a, b):
@@ -52,22 +92,38 @@ def cholesky(A: jax.Array, block: int = BLOCK) -> jax.Array:
     ``block``), left-looking by column blocks.
 
     Step k: column block k minus the contribution of the finished blocks
-    (``L[:, :kb] @ L[kb:(k+1)b, :kb]ᵀ``, with the unfinished columns
-    masked to zero), its diagonal block factored, the rows below solved
-    against that factor, the rows above zeroed.  Finished columns are
-    kept in place, so the buffer ends as L.
+    (``L[lo:, :lo] @ L[lo:lo+b, :lo]ᵀ``, read through its band's static
+    rectangle, with the row block's unfinished columns masked to zero),
+    its diagonal block factored, the rows below solved against that
+    factor, the rows above zeroed.  Finished columns are kept in place,
+    so the buffer ends as L.
     """
     p = A.shape[0]
     assert A.shape == (p, p) and p % block == 0, (A.shape, block)
+    plan = band_plan(p, block)
+    band_of = jnp.asarray(np.repeat(np.arange(len(plan)),
+                                    [b.stop - b.first for b in plan]),
+                          jnp.int32)
     rows = jnp.arange(p)[:, None]
-    cols = jnp.arange(p)[None, :]
+
+    def banded(band):
+        r, c = band.row0, band.col_end
+
+        def update(L, lo):                  # column block lo, rows r:
+            col = jax.lax.dynamic_slice(L, (r, lo), (p - r, block))
+            if c:
+                row_blk = jax.lax.dynamic_slice(L, (lo, 0), (block, c))
+                row_blk = jnp.where(jnp.arange(c)[None, :] < lo, row_blk,
+                                    0.0)
+                col = col - _dot(L[r:, :c], row_blk.T)
+            return jnp.pad(col, ((r, 0), (0, 0)))   # rows above r: unused
+        return update
+
+    updates = [banded(band) for band in plan]
 
     def step(k, L):
         lo = k * block
-        done = jnp.where(cols < lo, L, 0.0)                     # L[:, :lo]
-        row_blk = jax.lax.dynamic_slice(done, (lo, 0), (block, p))
-        col = (jax.lax.dynamic_slice(L, (0, lo), (p, block))
-               - _dot(done, row_blk.T))                         # (p, b)
+        col = jax.lax.switch(band_of[k], updates, L, lo)        # (p, b)
         diag = jax.lax.dynamic_slice(col, (lo, 0), (block, block))
         Lkk = jnp.linalg.cholesky(diag)
         below = jax.lax.linalg.triangular_solve(

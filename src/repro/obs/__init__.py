@@ -34,7 +34,8 @@ Span naming convention: dotted ``<tier>.<phase>[.<subphase>]`` —
 ``fit.chunk.pad`` (a ragged chunk's zero-pad) / ``fit.chunk.h2d`` /
 ``fit.chunk.wait`` (each streamed chunk),
 ``fit.cv.solve`` / ``fit.cv.score`` (each fold, inside ``fit.eigh``,
-whose ``method`` attribute says which factorisation ran),
+whose ``method`` attribute says which factorisation ran, and on the
+per-λ path ``bands`` and ``factor_update_flops`` which band plan),
 ``prefetch.stage`` / ``prefetch.wait`` / ``prefetch.compute_stall`` /
 ``prefetch.close``,
 ``wholebrain.block`` / ``wholebrain.xstats``, ``serve.wave.build`` /

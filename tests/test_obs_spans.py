@@ -167,6 +167,11 @@ def test_cv_spans_per_fold(stats, per_lambda, method):
     ev = tracer.events()
     (outer,) = [e for e in ev if e["name"] == "fit.eigh"]
     assert outer["attrs"]["method"] == method
+    if per_lambda:      # the band plan that ran, from the shape
+        assert outer["attrs"]["bands"] == len(spd.band_plan(P))
+        assert outer["attrs"]["factor_update_flops"] == spd.update_flops(P)
+    else:
+        assert "bands" not in outer["attrs"]
     for name in ("fit.cv.solve", "fit.cv.score"):
         spans = [e for e in ev if e["name"] == name]
         assert [e["attrs"]["fold"] for e in spans] == list(range(K))

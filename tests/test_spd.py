@@ -14,17 +14,43 @@ from repro.core.ridge import RidgeCVConfig
 from repro.data import fmri
 
 
-@pytest.mark.parametrize("p,block", [(64, 16), (100, 32), (300, 128)])
+# Band plans of ``spd.cholesky`` (``spd.BANDS`` = 8): one step; three
+# steps of an identity-padded p; fewer steps than bands; a step count not
+# divisible by the bands, padded or not; a multiple of the bands.
+@pytest.mark.parametrize("p,block", [(64, 16), (100, 32), (300, 128),
+                                     (32, 32), (200, 16), (192, 16),
+                                     (256, 16)])
 def test_ridge_solve_matches_float64(p, block):
-    """Including p not a multiple of the block (identity-padded tail)."""
+    """Factor and solve against float64, including p not a multiple of
+    the block (identity-padded tail)."""
     rng = np.random.default_rng(p)
     X = rng.standard_normal((2 * p, p))
     G, C = X.T @ X, rng.standard_normal((p, 7))
+    A = spd._padded(jnp.asarray(G + 3.0 * np.eye(p), jnp.float32), block)
+    L = np.asarray(spd.cholesky(A, block=block), np.float64)
+    L_ref = np.linalg.cholesky(np.asarray(A, np.float64))
+    assert np.all(np.triu(L, 1) == 0)
+    assert np.linalg.norm(L - L_ref) / np.linalg.norm(L_ref) < 1e-5
     W = spd.ridge_solve(jnp.asarray(G, jnp.float32),
                         jnp.asarray(C, jnp.float32), jnp.float32(3.0),
                         block=block)
     ref = np.linalg.solve(G + 3.0 * np.eye(p), C)
     assert np.linalg.norm(np.asarray(W) - ref) / np.linalg.norm(ref) < 1e-5
+
+
+def test_band_plan_at_paper_width():
+    """At p = 16,384 and the 512 block the bands cover every step once,
+    each band's static rectangle holds every step's live rows and
+    finished columns, and the updates cost under a quarter of 2p³."""
+    p, block = 16_384, spd.BLOCK
+    plan = spd.band_plan(p, block)
+    assert len(plan) == spd.BANDS
+    steps = [k for band in plan for k in range(band.first, band.stop)]
+    assert steps == list(range(p // block))
+    for band in plan:
+        for k in range(band.first, band.stop):
+            assert band.row0 <= k * block <= band.col_end
+    assert spd.update_flops(p, block) <= 0.25 * 2 * p ** 3
 
 
 def test_cholesky_factor_is_lower_and_exact():
