@@ -6,7 +6,8 @@ One command runs one cell of ``BENCHMARK.json``::
 
 Everything a cell needs is found by name: its configuration in
 ``bench/configs/<file>``, its traffic mix in ``bench/traffic/<name>.json``,
-its correctness limits in ``bench/limits/<cell>.json`` and each metric's
-reader in ``bench/metrics/<metric>.py``.  A new cell, configuration or
-metric is new files plus ``BENCHMARK.json`` entries.
+its correctness limits in ``bench/limits/<cell>.json``, the driver of its
+traffic's ``kind`` in ``bench/drivers/`` and each metric's reader in
+``bench/metrics/<metric>.py``.  A new cell, configuration, metric or kind
+of run is new files plus ``BENCHMARK.json`` entries.
 """

@@ -12,6 +12,7 @@ compared: the control moves it no more than sound runs do, see PERF.md.)
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import time
 
@@ -19,6 +20,8 @@ import numpy as np
 
 from bench import gen, reference
 from bench.harness import Check, Harness, log
+
+KINDS = ("fit_back_to_back",)
 
 
 def _encoder_config(config: dict, budget: int):
@@ -114,6 +117,53 @@ def control_answer(config: dict, precision: str):
         W = reference.ridge_refit(G, C, lam, jitter, precision, check=False)
         return {"lambda": lam, "curve": curve, "W": np.asarray(W)}
     return program
+
+
+def control_checks(cell, seed: int, seconds: float,
+                   precision: str) -> list[Check]:
+    """The checks with the reference at ``precision`` in the program's
+    place (``seconds`` plays no part: a fit's answer does not depend on
+    the window)."""
+    cfg = cell.config
+    return compare(cfg, cell.limits, seed, [], cfg["precision"],
+                   program=control_answer(cfg, precision))
+
+
+def tiny(cell):
+    """The cell at a size the CPU fits in a test, streamed in four
+    chunks."""
+    cfg = dict(cell.config, n=1536, p=128, t=32)
+    cfg["program"] = dict(cfg["program"], chunk_rows=384)
+    return dataclasses.replace(cell, config=cfg)
+
+
+def faults(kind: str) -> dict:
+    """Planted faults: the chunk update returns its state unchanged, or
+    leaves out half of its rows; the solve's answer is altered."""
+    import jax.numpy as jnp
+    from repro.core import foldstats, ridge
+
+    update = foldstats._FixedShapeUpdate.__call__
+    solve = ridge.ridge_cv_from_stats
+
+    def unchanged(self, stats, *a, **k):
+        return stats
+
+    def half_batch(self, stats, X, Y, onehot, slot_fold, **k):
+        onehot = jnp.asarray(onehot)
+        onehot = onehot.at[onehot.shape[0] // 2:].set(0)
+        return update(self, stats, X, Y, onehot, slot_fold, **k)
+
+    def altered(stats, cfg):
+        res = solve(stats, cfg)
+        return dataclasses.replace(
+            res, weights=res.weights.at[:, 0].multiply(1.01))
+
+    return {"state_unchanged": (foldstats._FixedShapeUpdate, "__call__",
+                                unchanged),
+            "half_batch": (foldstats._FixedShapeUpdate, "__call__",
+                           half_batch),
+            "answer_altered": (ridge, "ridge_cv_from_stats", altered)}
 
 
 def setup_store(h: Harness, seed: int, config: dict) -> str:

@@ -14,6 +14,7 @@ Pearson r.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import math
 import queue
@@ -23,7 +24,9 @@ import time
 import numpy as np
 
 from bench import arrivals, gen, reference
-from bench.harness import Check, Harness, log
+from bench.harness import Check, Harness, RunRecord, log
+
+KINDS = ("open_loop", "closed_loop")
 
 
 def _name(s: int) -> str:
@@ -225,6 +228,74 @@ def compare(config, limits, seed, reqs, answers, cols, xpool, ypool,
         checks.append(Check("pearson_abs_err", r_err,
                             limits["pearson_abs_err"]))
     return checks
+
+
+def control_checks(cell, seed: int, seconds: float,
+                   precision: str) -> list[Check]:
+    """The checks with the reference at ``precision`` in the program's
+    place, on the requests a run of ``seconds`` would compare."""
+    cfg = cell.config
+    rec = RunRecord(cell=cell.name, kind=cell.traffic["kind"], config=cfg,
+                    traffic=cell.traffic, limits=cell.limits,
+                    device_kind="")
+    reqs = schedule(rec, seed, seconds)
+    want = cell.traffic["check_requests"]
+    sample = (arrivals.check_sample([r.rows for r in reqs], want, seed)
+              if rec.kind == "open_loop" else list(range(want)))
+    cols = {i: arrivals.check_columns(cfg["t"], cell.traffic["check_columns"],
+                                      seed, i) for i in sample}
+    xpool, ypool = pools(rec, seed)
+    return compare(cfg, cell.limits, seed, reqs, {}, cols, xpool, ypool,
+                   control=precision)
+
+
+def tiny(cell):
+    """The cell at a size the CPU serves in a test: two wave shapes, a
+    small pool, a few dozen requests."""
+    cfg, tr = dict(cell.config, p=64, t=128), dict(cell.traffic)
+    cfg["serving"] = dict(cfg["serving"], wave_buckets=[16, 64],
+                          max_pending_rows=4096)
+    tr.update(pool_rows=256, check_columns=24)
+    if tr["kind"] == "open_loop":
+        tr.update(rows=[8, 64], rate_rows_per_s=800, check_requests=1000)
+    else:
+        tr.update(rows=[24, 49], max_requests=48, check_requests=48)
+    return dataclasses.replace(cell, config=cfg, traffic=tr)
+
+
+def faults(kind: str) -> dict:
+    """Planted faults in the wave program: the Pearson sums come back
+    unchanged, half of the wave's rows are left out, or a prediction is
+    altered.  The open-loop mix is unscored, so it keeps no state across
+    waves and cannot have the first."""
+    from repro.serving_encoders.service import EncoderService
+
+    init = EncoderService.__init__
+
+    def wrap(fault):
+        def patched(self, *a, **k):
+            init(self, *a, **k)
+            self._predict_mixed = (lambda f: lambda *args: fault(f, *args))(
+                self._predict_mixed)
+        return patched
+
+    def unchanged(f, X, Yt, onehot, sums_in, *rest):
+        P, _ = f(X, Yt, onehot, sums_in, *rest)
+        return P, sums_in
+
+    def half_batch(f, X, Yt, onehot, sums_in, *rest):
+        m = X.shape[0] // 2
+        return f(X.at[m:].set(0), Yt, onehot.at[m:].set(0), sums_in, *rest)
+
+    def altered(f, *args):
+        P, sums = f(*args)
+        return P.at[0].multiply(1.01), sums
+
+    planted = [("half_batch", half_batch), ("answer_altered", altered)]
+    if kind != "open_loop":
+        planted.insert(0, ("state_unchanged", unchanged))
+    return {name: (EncoderService, "__init__", wrap(fn))
+            for name, fn in planted}
 
 
 def schedule(rec, seed: int, seconds: float) -> list[arrivals.Request]:

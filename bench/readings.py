@@ -14,11 +14,10 @@ readings).  One JSON line per seed, all in one process.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import time
 
-from bench import arrivals, run, spec
+from bench import drivers, run, spec
 
 # One precision step below each configuration's.
 CONTROL_PRECISION = {"highest": "high"}
@@ -31,25 +30,9 @@ def _print(kind: str, seed: int, checks) -> None:
 
 def control_checks(cell: spec.Cell, seed: int, seconds: float):
     """The compared numbers with the control in the program's place."""
-    cfg, lower = cell.config, CONTROL_PRECISION[cell.config["precision"]]
-    if cell.traffic["kind"] == "fit_back_to_back":
-        from bench.drivers import fit
-        return fit.compare(cfg, cell.limits, seed, [], cfg["precision"],
-                           program=fit.control_answer(cfg, lower))
-    from bench.drivers import serve
-    from bench.harness import RunRecord
-    rec = RunRecord(cell=cell.name, kind=cell.traffic["kind"], config=cfg,
-                    traffic=cell.traffic, limits=cell.limits,
-                    device_kind="")
-    reqs = serve.schedule(rec, seed, seconds)
-    want = cell.traffic["check_requests"]
-    sample = (arrivals.check_sample([r.rows for r in reqs], want, seed)
-              if rec.kind == "open_loop" else list(range(want)))
-    cols = {i: arrivals.check_columns(cfg["t"], cell.traffic["check_columns"],
-                                      seed, i) for i in sample}
-    xpool, ypool = serve.pools(rec, seed)
-    return serve.compare(cfg, cell.limits, seed, reqs, {}, cols, xpool,
-                         ypool, control=lower)
+    lower = CONTROL_PRECISION[cell.config["precision"]]
+    return drivers.module(cell.traffic["kind"], cell.root).control_checks(
+        cell, seed, seconds, lower)
 
 
 def main(argv=None) -> int:
@@ -63,11 +46,7 @@ def main(argv=None) -> int:
     run.setup_jax()
     devs = run.find_chips(cell.chips)
     budget = int(devs[0].memory_stats()["bytes_limit"])
-    for i, s in enumerate(filter(None, args.seeds.split(","))):
-        if i and "warmup_fits" in cell.traffic:
-            # Compiled in this process already: one fit per seed is enough.
-            cell = dataclasses.replace(
-                cell, traffic=dict(cell.traffic, warmup_fits=0))
+    for s in filter(None, args.seeds.split(",")):
         rec = run.execute(cell, int(s), args.seconds, False,
                           device_kind=devs[0].device_kind, budget=budget,
                           t_start=time.perf_counter())

@@ -74,8 +74,7 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
             device_kind: str, budget: int, t_start: float):
     """Set up, run and check one cell on the current JAX backend; returns
     the run record.  Callers check the chips first."""
-    from bench import xplane
-    from bench.drivers import driver
+    from bench import drivers, xplane
     from bench.harness import Harness, RunRecord
 
     rec = RunRecord(cell=cell.name, kind=cell.traffic["kind"],
@@ -84,7 +83,7 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     h = Harness(rec, trace=trace, workdir=os.path.join(WORK_DIR, cell.name),
                 t_start=t_start)
     try:
-        driver(rec.kind)(h, seed, seconds, budget)
+        drivers.module(rec.kind, cell.root).run(h, seed, seconds, budget)
         if trace:
             spans = [s for s in rec.spans if s["tid"] == rec.main_thread]
             rec.trace = xplane.reduce(
@@ -99,7 +98,7 @@ def result(cell: spec.Cell, rec, trace: bool, device: dict) -> dict:
     """The result line: every metric of the cell that has a reading."""
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
-        v = spec.reader(m.name)(rec)
+        v = spec.reader(m.name, cell.root)(rec)
         if v is not None:
             metrics[m.name] = {"value": float(v), "unit": m.unit}
     device = dict(device, memory_peak_bytes=int(rec.memory_peak_bytes))

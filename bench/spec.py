@@ -37,6 +37,7 @@ class Cell:
     limits: dict
     end_to_end: tuple[Metric, ...]
     per_layer: tuple[Metric, ...]
+    root: str = ROOT                    # the checkout its files came from
 
 
 def _read_json(path: str) -> dict:
@@ -94,7 +95,7 @@ def assemble(name: str, config: str, traffic: str, chips: int = 1,
                  else m.moves in moved)]
     return Cell(name=name, config_name=config, traffic_name=traffic,
                 chips=chips, config=cfg, traffic=mix, limits=limits,
-                end_to_end=tuple(e2e), per_layer=tuple(layer))
+                end_to_end=tuple(e2e), per_layer=tuple(layer), root=root)
 
 
 def reader(metric: str, root: str = ROOT):
@@ -106,8 +107,13 @@ def reader(metric: str, root: str = ROOT):
     path = os.path.join(root, "bench", "metrics", f"{metric}.py")
     if not os.path.exists(path):
         raise SpecError(f"no reader bench/metrics/{metric}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
+    return load_file(path, f"bench_metric_{metric}").read
+
+
+def load_file(path: str, name: str):
+    """The Python file ``path`` run as a module called ``name``."""
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"),
+                                                  path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod

@@ -15,7 +15,7 @@ import time
 import numpy as np
 import pytest
 
-from bench import arrivals, peaks, spec, work
+from bench import arrivals, drivers, peaks, spec, work
 
 ROOT = spec.ROOT
 BENCH = spec.load_benchmark()
@@ -26,13 +26,17 @@ DATA = os.path.join(os.path.dirname(__file__), "data")
 
 # -- lookup by name ------------------------------------------------------------
 
+DRIVER_API = ("run", "control_checks", "tiny", "faults")
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_every_cell_resolves(cell):
     c = spec.cell(cell)
     assert (c.config_name, c.traffic_name) == KINDS[cell]
     assert c.config["name"] == c.config_name
-    assert c.traffic["kind"] in ("fit_back_to_back", "open_loop",
-                                 "closed_loop")
+    mod = drivers.module(c.traffic["kind"])
+    assert c.traffic["kind"] in mod.KINDS
+    assert all(callable(getattr(mod, f)) for f in DRIVER_API)
     assert {m.name for m in c.end_to_end} >= {"setup_s"}
     assert len(c.end_to_end) >= 2 and c.per_layer
     assert c.limits
@@ -90,6 +94,138 @@ def test_a_cell_added_by_files_alone(tmp_path):
     rec = dataclasses.make_dataclass("R", ["requests"])([{"rows": 3},
                                                          {"rows": 4}])
     assert spec.reader("burst.rows", root=str(tmp_path))(rec) == 7
+
+
+# A driver of a new kind of run, as a later change would add it: it
+# echoes a seeded vector through a compiled program for the window.
+ECHO_DRIVER = '''
+import dataclasses
+import time
+
+import numpy as np
+
+from bench.harness import Check
+
+KINDS = ("echo",)
+
+
+def _vector(config, seed):
+    rng = np.random.default_rng(seed)
+    return rng.standard_normal(config["n"]).astype(np.float32)
+
+
+def _checks(config, limits, got, seed):
+    err = float(np.max(np.abs(got - _vector(config, seed))))
+    return [Check("echo_abs_err", err, limits["echo_abs_err"])]
+
+
+def run(h, seed, seconds, budget):
+    import jax
+    import jax.numpy as jnp
+
+    rec = h.rec
+    echo = jax.jit(lambda v: v * 1.0)
+    x = jnp.asarray(_vector(rec.config, seed), jnp.float32)
+    echo(x).block_until_ready()
+    with h.window():
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < seconds:
+            y = echo(x)
+            rec.attempted += 1
+        y.block_until_ready()
+    rec.checks = _checks(rec.config, rec.limits, np.asarray(y), seed)
+
+
+def control_checks(cell, seed, seconds, precision):
+    lower = _vector(cell.config, seed).astype(np.float16)
+    return _checks(cell.config, cell.limits, lower, seed)
+
+
+def tiny(cell):
+    return dataclasses.replace(cell, config=dict(cell.config, n=8))
+
+
+def faults(kind):
+    return {}
+'''
+
+
+def _add_echo_cell(tmp_path) -> dict[str, bytes]:
+    """A copy of the benchmark with a cell of the new kind ``echo`` added
+    as files alone; returns the copy's files as they were before."""
+    shutil.copytree(os.path.join(ROOT, "bench"), tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    before = {str(f.relative_to(tmp_path)): f.read_bytes()
+              for f in (tmp_path / "bench").rglob("*") if f.is_file()}
+    b = json.loads(json.dumps(BENCH))
+    b["configs"].append({"name": "echo", "source": "x",
+                         "file": "bench/configs/echo.json", "reduced": [],
+                         "why": "x"})
+    b["workloads"].append({"name": "echo.echo", "config": "echo",
+                           "traffic": "echo", "chips": 1, "why": "x"})
+    b["end_to_end"].append({"name": "echo_per_s", "unit": "1/s",
+                            "better": "higher", "bound": 0.1,
+                            "source": "host_clock",
+                            "workloads": ["echo.echo"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    (tmp_path / "bench/drivers/echo.py").write_text(ECHO_DRIVER)
+    (tmp_path / "bench/configs/echo.json").write_text(json.dumps(
+        {"name": "echo", "n": 4096, "precision": "highest"}))
+    (tmp_path / "bench/traffic/echo.json").write_text(json.dumps(
+        {"kind": "echo"}))
+    (tmp_path / "bench/limits/echo.echo.json").write_text(json.dumps(
+        {"echo_abs_err": 0.0}))
+    (tmp_path / "bench/metrics/echo_per_s.py").write_text(
+        "def read(rec):\n    return rec.attempted / rec.window_s\n")
+    return before
+
+
+def test_a_new_kind_of_run_added_by_files_alone(tmp_path):
+    """A new kind of run is a driver file, a traffic mix of its kind, a
+    configuration and limits, plus BENCHMARK.json entries: the driver is
+    found by its kind, and its tiny run on the CPU is correct."""
+    from bench import run
+    before = _add_echo_cell(tmp_path)
+    root = str(tmp_path)
+    mod = drivers.module("echo", root=root)
+    assert mod.KINDS == ("echo",)
+    c = spec.cell("echo.echo", root=root)
+    assert c.root == root and c.traffic["kind"] == "echo"
+    assert drivers.module("fit_back_to_back", root=root).KINDS == (
+        "fit_back_to_back",)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(run, "WORK_DIR", str(tmp_path / "work"))
+        rec = execute(mod.tiny(c))
+    out = run.result(c, rec, False, {"platform": "cpu", "kind": "cpu",
+                                     "count": 1})
+    assert out["correct"] is True and rec.attempted > 0
+    assert set(out["metrics"]) == {"setup_s", "echo_per_s"}
+    # Its control, a step below in precision, reads incorrect.
+    assert not all(ch.ok for ch in mod.control_checks(c, BIG_SEED, 0.5,
+                                                      "high"))
+    # No file that was already there was edited.
+    assert {k: (tmp_path / k).read_bytes() for k in before} == before
+
+
+def test_an_unknown_kind_is_an_error():
+    with pytest.raises(spec.SpecError):
+        drivers.module("no_such_kind")
+
+
+def test_a_kind_claimed_by_two_drivers_is_an_error(tmp_path):
+    shutil.copytree(os.path.join(ROOT, "bench"), tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    for name in ("one", "two"):
+        (tmp_path / f"bench/drivers/{name}.py").write_text(
+            'KINDS = ("twice",)\n')
+    (tmp_path / "bench/drivers/three.py").write_text(
+        'KINDS = ("closed_loop",)\n')
+    with pytest.raises(spec.SpecError):
+        drivers.module("twice", root=str(tmp_path))
+    with pytest.raises(spec.SpecError):
+        drivers.module("closed_loop", root=str(tmp_path))
+    assert drivers.module("open_loop", root=str(tmp_path)).KINDS == (
+        "open_loop", "closed_loop")
 
 
 # -- generators ----------------------------------------------------------------
@@ -232,6 +368,27 @@ def test_trace_reduction_on_a_recorded_chip_trace():
     assert "probe.step" in dict(bd["idle_gaps"])
 
 
+def test_device_time_by_scope_on_a_recorded_chip_trace():
+    from bench import xplane
+    raw = xplane.load(os.path.join(DATA, "small_trace.xplane.pb"))
+    t = xplane.reduce(raw)
+    assert "jit(xty_folds_masked)/pallas_call" in t["scopes"]
+    secs, count = xplane.scope_time(t, "xty_folds_masked")
+    kernel, _ = xplane.op_time(t, "xty_folds_masked")
+    assert count == 8 and kernel <= secs <= t["busy_s"]
+    # A scope is a whole component of the path, after jit(...).
+    assert xplane.scope_time(t, "pad")[1] == 2
+    assert xplane.scope_time(t, "xty_folds") == (0, 0)
+    assert xplane.scope_parts("jit(a)/pjit(b)/c/jit(d)") == ["a", "b", "c", "d"]
+    # Ops with no tf_op (the async copies, the slices' start and done)
+    # fall under no scope: the scopes hold the rest of the ops' time.
+    unscoped = [n for n, p in zip(raw["device"]["/device:TPU:0"]["XLA Ops"],
+                                  raw["scopes"]["/device:TPU:0"]) if not p]
+    assert unscoped and "" not in t["scopes"]
+    assert sum(t["scope_count"].values()) < sum(t["op_count"].values())
+    assert sum(t["scopes"].values()) < sum(t["ops"].values())
+
+
 def test_idle_gaps_take_the_innermost_open_span():
     from bench import xplane
     host = [("outer", 0.0, 10.0, 0), ("inner", 2.0, 2.0, 1),
@@ -242,39 +399,34 @@ def test_idle_gaps_take_the_innermost_open_span():
 
 # -- whole runs at tiny sizes, on the CPU --------------------------------------------
 
-# Every kind of traffic the drivers serve: the cells of BENCHMARK.json and
-# the open-loop mix kept for the predict cell (PERF.md, Open questions).
-KINDS = {"parcels.fit": ("parcels", "fit"),
-         "wholebrain_q4.score": ("wholebrain_q4", "score"),
+# Every cell of BENCHMARK.json, and the open-loop mix kept for the predict
+# cell (PERF.md, Open questions, item 1).
+KINDS = {**{w["name"]: (w["config"], w["traffic"])
+            for w in BENCH["workloads"]},
          "wholebrain_q4.predict": ("wholebrain_q4", "predict")}
+CELLS = list(KINDS)
+
+
+def driver_of(cell: spec.Cell):
+    return drivers.module(cell.traffic["kind"], cell.root)
 
 
 def tiny(cell_name: str) -> spec.Cell:
     c = spec.assemble(cell_name, *KINDS[cell_name])
-    cfg, tr = dict(c.config), dict(c.traffic)
-    if tr["kind"] == "fit_back_to_back":
-        cfg.update(n=1536, p=128, t=32)
-        cfg["program"] = dict(cfg["program"], chunk_rows=384)
-    else:
-        cfg.update(p=64, t=128)
-        cfg["serving"] = dict(cfg["serving"], wave_buckets=[16, 64],
-                              max_pending_rows=4096)
-        tr.update(pool_rows=256, check_columns=24)
-        if tr["kind"] == "open_loop":
-            tr.update(rows=[8, 64], rate_rows_per_s=800, check_requests=1000)
-        else:
-            tr.update(rows=[24, 49], max_requests=48, check_requests=48)
-    return dataclasses.replace(c, config=cfg, traffic=tr)
+    return driver_of(c).tiny(c)
 
 
-def execute(cell: spec.Cell, seed: int = BIG_SEED, seconds: float = 0.5):
+# One byte of device memory: at a tiny size each cell still takes the
+# path its full size takes where the data outgrows the chip (a fit
+# streams its store).
+TINY_BUDGET = 1
+
+
+def execute(cell: spec.Cell, seed: int = BIG_SEED, seconds: float = 0.5,
+            trace: bool = False):
     from bench import run
-    return run.execute(cell, seed, seconds, False, device_kind="TPU v5 lite",
-                       budget=1 if "n" in cell.config else 1 << 40,
-                       t_start=time.perf_counter())
-
-
-CELLS = list(KINDS)
+    return run.execute(cell, seed, seconds, trace, device_kind="TPU v5 lite",
+                       budget=TINY_BUDGET, t_start=time.perf_counter())
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -304,75 +456,19 @@ def test_control_reads_incorrect(cell):
     assert [c for c in checks if not c.ok], checks
 
 
-def _fit_faults():
-    import jax.numpy as jnp
-    from repro.core import foldstats, ridge
-
-    update = foldstats._FixedShapeUpdate.__call__
-    solve = ridge.ridge_cv_from_stats
-
-    def unchanged(self, stats, *a, **k):
-        return stats
-
-    def half_batch(self, stats, X, Y, onehot, slot_fold, **k):
-        onehot = jnp.asarray(onehot)
-        onehot = onehot.at[onehot.shape[0] // 2:].set(0)
-        return update(self, stats, X, Y, onehot, slot_fold, **k)
-
-    def altered(stats, cfg):
-        res = solve(stats, cfg)
-        return dataclasses.replace(
-            res, weights=res.weights.at[:, 0].multiply(1.01))
-
-    return {"state_unchanged": (foldstats._FixedShapeUpdate, "__call__",
-                                unchanged),
-            "half_batch": (foldstats._FixedShapeUpdate, "__call__",
-                           half_batch),
-            "answer_altered": (ridge, "ridge_cv_from_stats", altered)}
+def _faults(cell_name: str) -> dict:
+    c = spec.assemble(cell_name, *KINDS[cell_name])
+    return driver_of(c).faults(c.traffic["kind"])
 
 
-def _serve_faults():
-    from repro.serving_encoders.service import EncoderService
-
-    init = EncoderService.__init__
-
-    def wrap(fault):
-        def patched(self, *a, **k):
-            init(self, *a, **k)
-            self._predict_mixed = (lambda f: lambda *args: fault(f, *args))(
-                self._predict_mixed)
-        return patched
-
-    def unchanged(f, X, Yt, onehot, sums_in, *rest):
-        P, _ = f(X, Yt, onehot, sums_in, *rest)
-        return P, sums_in
-
-    def half_batch(f, X, Yt, onehot, sums_in, *rest):
-        m = X.shape[0] // 2
-        return f(X.at[m:].set(0), Yt, onehot.at[m:].set(0), sums_in, *rest)
-
-    def altered(f, *args):
-        P, sums = f(*args)
-        return P.at[0].multiply(1.01), sums
-
-    return {name: (EncoderService, "__init__", wrap(fn)) for name, fn in
-            (("state_unchanged", unchanged), ("half_batch", half_batch),
-             ("answer_altered", altered))}
-
-
-# Which faults each kind of cell can have: the predict cell keeps no state
-# across waves, and no cell here exchanges anything between chips.
-FAULTS = [(cell, fault) for cell in CELLS
-          for fault in ("state_unchanged", "half_batch", "answer_altered")
-          if not (cell.endswith(".predict") and fault == "state_unchanged")]
+# The faults each cell's driver plants; no cell here exchanges anything
+# between chips.
+FAULTS = [(cell, fault) for cell in CELLS for fault in _faults(cell)]
 
 
 @pytest.mark.parametrize("cell,fault", FAULTS)
 def test_planted_fault_reads_incorrect(cell, fault, monkeypatch):
-    if cell.endswith(".fit"):
-        target, attr, fn = _fit_faults()[fault]
-    else:
-        target, attr, fn = _serve_faults()[fault]
+    target, attr, fn = _faults(cell)[fault]
     monkeypatch.setattr(target, attr, fn)
     rec = execute(tiny(cell))
     assert rec.attempted > 0

@@ -3,18 +3,17 @@ by hand on a synthetic run record, silent where the program records no
 such span, and end to end on tiny traced runs of both cells."""
 from __future__ import annotations
 
-import time
-
 import pytest
 
 from bench import run, spec
 from bench.harness import RunRecord
-from bench.tests.test_bench import BIG_SEED, tiny
+from bench.tests.test_bench import execute, tiny
 
 FIT = ("fit.chunk_h2d_s", "fit.chunk_wait_s", "fit.cv_solve_s",
        "fit.cv_score_s")
 SCORE = ("score.h2d_ms", "score.h2d_bytes_per_row", "score.sums_wait_ms",
          "score.copyback_ms")
+SCOPES = ("fit.cv_solve_device_s", "score.wave_scan_device_ms")
 
 
 def _span(name, t0, dur, **attrs):
@@ -60,7 +59,29 @@ def test_score_span_readers_by_hand():
         assert spec.reader(metric)(rec) == pytest.approx(value), metric
 
 
-@pytest.mark.parametrize("metric", FIT + SCORE)
+def test_scope_readers_by_hand():
+    """Device time under a named scope, per fit and per wave program."""
+    rec = _record([], [{"t0": 0.0, "dur_s": 10.0},
+                       {"t0": 10.0, "dur_s": 10.0}])
+    scopes = {"jit(cv_solve)/cv_solve/while/body/dot_general": 3.0,
+              "jit(cv_solve)/cv_solve/triangular_solve": 0.5,
+              "jit(refit)/refit/while/body/dot_general": 0.75,
+              "jit(_predict_mixed)/wave_scan/while/body/add": 0.012,
+              "jit(_predict_mixed)/wave_matmul/dot_general": 0.1}
+    rec.trace = {"chips": 1, "scopes": scopes,
+                 "scope_count": {k: 1 for k in scopes},
+                 "modules": [("jit__predict_mixed(7)", 0.0, 0.05),
+                             ("jit__predict_mixed(7)", 1.0, 0.05),
+                             ("jit_cv_solve(9)", 2.0, 1.0)]}
+    assert spec.reader("fit.cv_solve_device_s")(rec) == pytest.approx(1.75)
+    assert spec.reader("score.wave_scan_device_ms")(rec) == pytest.approx(6.0)
+    # A trace with no such scope (a program that names none) is silent.
+    rec.trace = dict(rec.trace, scopes={}, scope_count={})
+    for metric in SCOPES:
+        assert spec.reader(metric)(rec) is None
+
+
+@pytest.mark.parametrize("metric", FIT + SCORE + SCOPES)
 def test_span_readers_silent_without_their_spans(metric):
     """A program that records none of the new spans (or an untraced run)
     leaves the metric out instead of raising."""
@@ -81,10 +102,7 @@ def traced_runs(tmp_path_factory):
         mp.setattr(run, "WORK_DIR", str(tmp_path_factory.mktemp("work")))
         for cell in ("parcels.fit", "wholebrain_q4.score"):
             c = tiny(cell)
-            rec = run.execute(c, BIG_SEED, 0.5, True,
-                              device_kind="TPU v5 lite",
-                              budget=1 if "n" in c.config else 1 << 40,
-                              t_start=time.perf_counter())
+            rec = execute(c, trace=True)
             out[cell] = (c, rec, run.result(c, rec, True, {
                 "platform": "cpu", "kind": "cpu", "count": 1}))
     return out
