@@ -36,7 +36,12 @@ _MODULES = {
     "gemma3-12b": "gemma3_12b",
     "seamless-m4t-medium": "seamless_m4t_medium",
     "gemma2-2b": "gemma2_2b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
+
+#: Feature backbones: run only as brain-encoding feature extractors
+#: (``repro.encoding.backbone``), one chip's share of a deployment each.
+BACKBONE_IDS = ("granite-4.0-h-small",)
 
 
 def get_config(arch: str) -> ModelConfig:
@@ -44,6 +49,18 @@ def get_config(arch: str) -> ModelConfig:
         raise KeyError(f"unknown arch {arch!r}; known: {sorted(_MODULES)}")
     mod = importlib.import_module(f"repro.configs.{_MODULES[arch]}")
     return mod.CONFIG
+
+
+def tiny_backbone(cfg: ModelConfig, *, d_model: int = 64, experts: int = 8,
+                  top_k: int = 2, held: int = 4) -> ModelConfig:
+    """A feature backbone at a size the CPU runs in a test: the same layer
+    pattern and mechanisms, narrow widths, ``held`` of ``experts``."""
+    return dataclasses.replace(
+        cfg, name=cfg.name + "-tiny", d_model=d_model, n_heads=4,
+        n_kv_heads=2, head_dim=16, d_ff=32, vocab=97,
+        moe=dataclasses.replace(cfg.moe, n_experts=experts, top_k=top_k,
+                                held=tuple(range(held)), d_expert=16),
+        ssm=dataclasses.replace(cfg.ssm, d_state=8, head_dim=8, chunk=8))
 
 
 def smoke(cfg: ModelConfig) -> ModelConfig:

@@ -319,6 +319,17 @@ def chunk_update_compiles() -> "obs.CompileCounter":
     return _FIXED_UPDATE.compiles
 
 
+def _pad_rows(a, rows: int):
+    """``a`` zero-padded to ``rows`` rows: on the device for a device
+    array (a chunk made there never visits the host), else on the host."""
+    import numpy as np
+    if isinstance(a, jax.Array):
+        return jnp.pad(a, ((0, rows - a.shape[0]), (0, 0)))
+    out = np.zeros((rows, a.shape[1]), np.asarray(a).dtype)
+    out[:a.shape[0]] = a
+    return out
+
+
 def send_chunk(put, Xs, Ys, onehot, slot_fold):
     """``(put(Xs), put(Ys))`` inside one ``fit.chunk.h2d`` span.
 
@@ -328,7 +339,8 @@ def send_chunk(put, Xs, Ys, onehot, slot_fold):
     for the two copies, so it times the transfer; with none installed
     nothing here waits.
     """
-    nbytes = int(Xs.nbytes + Ys.nbytes + onehot.nbytes + slot_fold.nbytes)
+    nbytes = int(sum(a.nbytes for a in (Xs, Ys, onehot, slot_fold)
+                     if not isinstance(a, jax.Array)))
     with obs.transfer("fit.chunk.h2d", "h2d_bytes", nbytes, site="chunk",
                       rows=int(Xs.shape[0])):
         X, Y = put(Xs), put(Ys)
@@ -465,10 +477,7 @@ class FoldStatsAccumulator:
             Xs, Ys = X_chunk[lo:hi], Y_chunk[lo:hi]
             if hi - lo < fixed:             # ragged: zero-pad to the shape
                 with obs.span("fit.chunk.pad", rows=hi - lo):
-                    Xp = np.zeros((fixed, Xs.shape[1]), np.asarray(Xs).dtype)
-                    Yp = np.zeros((fixed, Ys.shape[1]), np.asarray(Ys).dtype)
-                    Xp[:hi - lo], Yp[:hi - lo] = Xs, Ys
-                    Xs, Ys = Xp, Yp
+                    Xs, Ys = _pad_rows(Xs, fixed), _pad_rows(Ys, fixed)
             onehot, slot_fold = self._slot_mask(hi - lo)
             self._apply(Xs, Ys, onehot, slot_fold)
             self._offset += hi - lo

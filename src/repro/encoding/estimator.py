@@ -201,33 +201,42 @@ class BrainEncoder:
         (p×p) is the small object.  Chunks must arrive in global row order;
         the fold split matches ``fit`` on the concatenated rows.
 
-        ``chunks`` may also be a ``repro.data.store.RunStore`` — it is
-        streamed with ``config.chunk_rows`` (background-prefetched when
-        ``config.prefetch``) and ``n_total`` is taken from its manifest.
+        ``chunks`` may also be a ``repro.data.store.RunStore``, or any
+        source that reads like one (``shape``, ``n_folds``,
+        ``iter_chunks``; ``repro.encoding.backbone.BackboneSource``) — it
+        is streamed with ``config.chunk_rows`` (background-prefetched when
+        ``config.prefetch``), ``n_total`` is taken from its shape, and the
+        dispatch decision is resolved from that shape before the stream
+        starts, as ``fit(store=)`` resolves it: the same kernel tier and
+        the same solve.
         """
         self._check_chunkable()
         # A source that exposes PrefetchStats (a ChunkPrefetcher handed in
         # directly) contributes its overlap telemetry to stream_stats_.
         stream = chunks if hasattr(chunks, "stats") else None
+        decision = None
         if hasattr(chunks, "iter_chunks"):            # RunStore duck-type
             self._check_store_folds(chunks)
-            n_total = chunks.shape[0]
+            n_total, p, t = chunks.shape
+            decision = resolve(self._streamed_config(), n_total, p, t,
+                               jax.device_count())
             chunk_rows = chunk_rows or self.config.chunk_rows
             chunks = stream = chunks.iter_chunks(
                 chunk_rows, prefetch=self.config.prefetch,
                 prefetch_depth=self.config.prefetch_depth)
         if n_total is None:
             raise ValueError("fit_chunks needs n_total for iterator sources")
+        use_pallas = (decision.use_pallas if decision is not None
+                      else self.config.resolve_use_pallas())
         with obs.span("fit", mode="chunks"):
             compiles0 = foldstats.chunk_update_compile_count()
             with obs.span("fit.stats", n=n_total):
                 stats = foldstats.compute_chunked(
                     chunks, n_total, self.config.n_folds,
-                    chunk_rows=chunk_rows,
-                    use_pallas=self.config.resolve_use_pallas())
+                    chunk_rows=chunk_rows, use_pallas=use_pallas)
             self._record_stream_stats([stream] if stream is not None else [],
                                       compiles0)
-            return self._fit_from_stats(stats, n_total)
+            return self._fit_from_stats(stats, n_total, decision)
 
     def _check_store_folds(self, store) -> None:
         """The manifest's fold split is part of the store's data contract:
@@ -241,6 +250,11 @@ class BrainEncoder:
                 f"configured with n_folds={self.config.n_folds} — match "
                 f"EncoderConfig.n_folds to the store (or re-create the "
                 f"store with the intended split)")
+
+    def _streamed_config(self) -> EncoderConfig:
+        """The configuration a fit from streamed statistics resolves: the
+        single-shard primal ridge solve."""
+        return dataclasses.replace(self.config, solver="ridge", method="eigh")
 
     def _check_chunkable(self) -> None:
         if self.config.solver not in ("auto", "ridge"):
@@ -273,7 +287,7 @@ class BrainEncoder:
                 f"fit_chunks: target mean/std ratio {ratio:.0f} is too "
                 f"large for statistics-based CV scoring in float32 — "
                 f"standardize the targets first (pipeline.standardize)")
-        cfg = dataclasses.replace(self.config, solver="ridge", method="eigh")
+        cfg = self._streamed_config()
         if decision is None:
             decision = resolve(cfg, n_total, p, t, jax.device_count())
         res = ridge.ridge_cv_from_stats(stats,

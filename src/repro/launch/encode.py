@@ -1,13 +1,15 @@
 """Brain-encoding driver — the paper's full pipeline, end to end.
 
-stimulus features (backbone hidden states or synthetic VGG16-shaped
-features) → ``BrainEncoder`` (solver picked by complexity-driven dispatch:
-distributed B-MOR on a multi-device mesh, mutualised RidgeCV otherwise) →
-Pearson-r encoding map + null permutation control.
+stimulus features (synthetic VGG16-shaped features, or a feature
+backbone's hidden states) → ``BrainEncoder`` (solver picked by
+complexity-driven dispatch: distributed B-MOR on a multi-device mesh,
+mutualised RidgeCV otherwise) → Pearson-r encoding map + null permutation
+control.
 
-``python -m repro.launch.encode --backbone qwen3-1.7b --smoke`` runs the
-whole thing on CPU; ``--features vgg16`` uses the paper-faithful synthetic
-feature pipeline instead of a transformer backbone.
+``python -m repro.launch.encode --smoke`` runs the paper-shaped synthetic
+pipeline on CPU; ``--backbone granite-4.0-h-small --smoke --store DIR``
+streams a backbone's delayed features from transcripts through the same
+fit (``repro.encoding.backbone``).
 """
 from __future__ import annotations
 
@@ -74,6 +76,80 @@ def _run_store_mode(args) -> None:
                                              "shape": list(store.shape)})
 
 
+def _run_backbone_mode(args) -> None:
+    """Backbone features through the normal streamed fit.
+
+    ``--store DIR`` opens a subject written by
+    ``repro.encoding.backbone.write_subject`` (targets plus one transcript
+    per run), or first writes a synthetic one: random transcripts, and
+    targets planted on the backbone's own delayed features, in runs of
+    480 TRs.  The fit then
+    streams ``BackboneSource`` chunks through ``BrainEncoder.fit_chunks``.
+    """
+    import os
+
+    import jax
+    import numpy as np
+    from repro import configs
+    from repro.data.store import MANIFEST_NAME, RunStore
+    from repro.encoding import BrainEncoder, EncoderConfig
+    from repro.encoding import backbone as bb
+    from repro.models import build_model
+    from repro.models.reference.granite import delays
+
+    cfg = configs.get_config(args.backbone)
+    if args.backbone not in configs.BACKBONE_IDS:
+        raise SystemExit(f"{args.backbone!r} is not a feature backbone; "
+                         f"known: {configs.BACKBONE_IDS}")
+    if args.smoke:
+        cfg = configs.tiny_backbone(cfg)
+    params = build_model(cfg).init(jax.random.PRNGKey(1))
+    budget = int(args.budget_mb * 2**20)
+    extractor = bb.Extractor(cfg, params, budget=budget)
+    if not os.path.exists(os.path.join(args.store, MANIFEST_NAME)):
+        rng = np.random.default_rng(0)
+        runs = [min(480, args.n - lo) for lo in range(0, args.n, 480)]
+        scripts = []
+        for trs in runs:
+            per = np.where(rng.random(trs) < 0.25, 0,
+                           1 + rng.poisson(3.0, trs))
+            end = np.cumsum(per)
+            scripts.append(bb.Transcript(
+                tokens=rng.integers(0, cfg.vocab, int(end[-1]),
+                                    dtype=np.int32),
+                tr_end=(end - 1).astype(np.int32)))
+        F = np.asarray(extractor.extract(scripts, args.n))[4:4 + args.n]
+        X = delays(F, runs, extractor.lags)
+        W = rng.standard_normal((X.shape[1], args.targets)) / np.sqrt(
+            X.shape[1])
+        Y = X / X.std() @ W * 2.0 + rng.standard_normal((args.n,
+                                                          args.targets))
+        Y = ((Y - Y.mean(0)) / Y.std(0)).astype(np.float32)
+        offs = np.cumsum([0] + runs)
+        bb.write_subject(args.store, scripts,
+                         [Y[a:b] for a, b in zip(offs[:-1], offs[1:])],
+                         n_folds=5)
+        print(f"wrote a synthetic subject of {len(runs)} runs to "
+              f"{args.store}")
+    src = bb.BackboneSource(extractor, RunStore.open(args.store))
+    enc = BrainEncoder(EncoderConfig(device_memory_budget=budget,
+                                     chunk_rows=args.chunk_rows))
+    enc.fit_chunks(src)
+    d = enc.report_.decision
+    print(f"backbone features from {cfg.name}: n={src.shape[0]} "
+          f"p={src.shape[1]} t={src.shape[2]} ({extractor.last['tokens']} "
+          f"tokens in {extractor.last['batches']} batches)")
+    print(f"dispatch: solver={d.solver} method={d.method} "
+          f"use_pallas={d.use_pallas}")
+    print(f"{enc.report_.solver_label} fit: λ = {enc.report_.best_lambda}, "
+          f"CV scores {enc.report_.cv_scores.round(4)}")
+    if args.save_bundle:
+        _save_bundle_with_report(enc, args.save_bundle,
+                                 provenance={"source": "backbone",
+                                             "backbone": cfg.name,
+                                             "store": args.store})
+
+
 def _save_bundle_with_report(encoder, bundle_dir: str,
                              provenance: dict | None = None) -> None:
     """Persist the fitted encoder + machine-readable run provenance.
@@ -93,7 +169,9 @@ def _save_bundle_with_report(encoder, bundle_dir: str,
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--backbone", default="vgg16",
-                    help="arch id or 'vgg16' for the paper's feature shape")
+                    help="'vgg16' for the paper's feature shape, or a "
+                         "feature backbone (configs.BACKBONE_IDS) with "
+                         "--store")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--n", type=int, default=512, help="time samples")
     ap.add_argument("--targets", type=int, default=256)
@@ -128,47 +206,29 @@ def main() -> None:
 
 
 def _run(args) -> None:
+    if args.backbone != "vgg16":
+        if args.store is None:
+            raise SystemExit("a backbone's features stream from a store: "
+                             "pass --store DIR")
+        _run_backbone_mode(args)
+        return
     if args.store is not None:
         _run_store_mode(args)
         return
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
-    from repro import configs
-    from repro.data import fmri, synthetic
+    from repro.data import fmri
     from repro.encoding import EncoderConfig, pipeline
-    from repro.models import build_model
 
     n, t = args.n, args.targets
     key = jax.random.PRNGKey(0)
 
-    # 1. Stimulus features X.
-    if args.backbone == "vgg16":
-        spec = fmri.SubjectSpec(n=n, p=128, t=t)
-        X, Y, mask = fmri.generate(key, spec)
-        print(f"synthetic VGG16-shaped features: X{X.shape} Y{Y.shape}")
-    else:
-        cfg = configs.get_config(args.backbone)
-        if args.smoke:
-            cfg = configs.smoke(cfg)
-        model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(1))
-        seq = 16
-        batch = synthetic.make_batch(jax.random.PRNGKey(2), cfg,
-                                     n // seq, seq)
-        h = jax.jit(model.hidden_states)(params, batch)   # (B, S, d)
-        X = h.reshape(-1, h.shape[-1]).astype(jnp.float32)
-        X = (X - X.mean(0)) / (X.std(0) + 1e-6)
-        spec = fmri.SubjectSpec(n=X.shape[0], p=X.shape[1], t=t)
-        _, Y, mask = fmri.generate(key, spec)
-        # Plant signal from THESE features so encoding is learnable.
-        W_true = jax.random.normal(jax.random.PRNGKey(3),
-                                   (X.shape[1], t)) / np.sqrt(X.shape[1])
-        W_true = W_true * jnp.where(mask, 1.0, 0.0)[None, :]
-        Y = X @ W_true * 2.0 + jax.random.normal(jax.random.PRNGKey(4),
-                                                 Y.shape)
-        print(f"backbone features from {cfg.name}: X{X.shape} Y{Y.shape}")
+    # 1. Stimulus features X (the paper's shape; backbones go through
+    # --store, _run_backbone_mode).
+    spec = fmri.SubjectSpec(n=n, p=128, t=t)
+    X, Y, mask = fmri.generate(key, spec)
+    print(f"synthetic VGG16-shaped features: X{X.shape} Y{Y.shape}")
 
     # 2-4. 90/10 split → standardize (train-fitted) → fit → evaluate, through
     # the unified estimator API: no mesh/device_put boilerplate here, the

@@ -15,7 +15,8 @@ from typing import Literal
 
 import jax.numpy as jnp
 
-LayerKind = Literal["global_attn", "local_attn", "mamba", "shared_attn"]
+LayerKind = Literal["global_attn", "local_attn", "mamba", "shared_attn",
+                    "attention"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -25,6 +26,17 @@ class MoEConfig:
     capacity_factor: float = 1.25
     group_size: int = 4096        # dispatch group (bounds one-hot matmul cost)
     router_aux_weight: float = 0.01
+    # The experts this chip holds, by index into the router's
+    # ``n_experts`` outputs (None: all of them); the dropless layer
+    # (models/moe.moe_dropless) computes only their pairs.
+    held: tuple[int, ...] | None = None
+    # Width of one routed expert (None: ``ModelConfig.d_ff``).
+    d_expert: int | None = None
+
+    @property
+    def held_experts(self) -> tuple[int, ...]:
+        return self.held if self.held is not None else tuple(
+            range(self.n_experts))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,6 +53,10 @@ class SSMConfig:
     # of the XLA einsum chain (requires n_groups == 1).  TPU-only in
     # production (interpret-mode on CPU, for tests).
     use_kernel: bool = False
+    # Gated RMSNorm over each head's ``head_dim`` values ("head") or over
+    # each group's ``d_inner / n_groups`` values ("group", as Granite-4.0-H
+    # and the Mamba-2 reference kernels do).
+    norm: Literal["head", "group"] = "head"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +86,16 @@ class ModelConfig:
     ssm: SSMConfig | None = None
     shared_attn_every: int = 0           # zamba2: shared block cadence
     shared_attn_window: int | None = None  # window for the shared block
+    # What follows each layer's mixer: nothing (zamba2's mamba blocks), or
+    # a routed MoE plus a shared expert of width ``d_ff`` (granite-4.0-h).
+    ffn: Literal["none", "moe_shared"] = "none"
+    # Granite's scalings: the embedding is multiplied by
+    # ``embedding_multiplier``, each branch by ``residual_multiplier``
+    # before it is added, and attention scores by ``attention_multiplier``
+    # (its attention has no position encoding).
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None
     # Encoder-decoder (audio family): encoder depth; decoder uses n_layers.
     n_encoder_layers: int = 0
     # Modality frontend stub: number of prefix embedding tokens consumed.

@@ -1,10 +1,19 @@
-"""Zamba2-style hybrid backbone [arXiv:2411.15242]: Mamba2 blocks with a
-*shared* (weight-tied) attention+MLP block interleaved at a fixed cadence.
+"""Hybrid Mamba-2 backbones, scanned over repeats of a layer pattern.
 
-The repeating pattern is ``(mamba × k, shared_attn)``; the shared block's
-parameters live once at the top level and are closed over inside the
-``lax.scan`` body, so every application reuses the same weights (the defining
-property of Zamba2) while each application keeps its *own* KV cache slice.
+Each layer is a mixer — ``mamba``, ``attention`` (weights of its own) or
+``shared_attn`` — followed by an FFN: none, or a routed MoE plus a shared
+expert (``ModelConfig.ffn``).
+
+* Zamba2 [arXiv:2411.15242]: ``(mamba × k, shared_attn)``; the shared
+  attention+MLP block's parameters live once at the top level and are
+  closed over inside the ``lax.scan`` body, so every application reuses the
+  same weights (the defining property of Zamba2) while each application
+  keeps its *own* KV cache slice.  No FFN after the Mamba blocks.
+* Granite-4.0-H [hf:ibm-granite/granite-4.0-h-small]: a period of nine
+  Mamba-2 layers and one NoPE attention layer, each followed by a dropless
+  MoE and a shared expert, with Granite's embedding and residual
+  multipliers.  ``features`` runs it over packed runs for brain encoding
+  (``repro.encoding.backbone``).
 """
 from __future__ import annotations
 
@@ -14,7 +23,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models import layers, ssm
+from repro.models import layers, moe, ssm
 from repro.models.scanning import scan_blocks
 from repro.models.config import ModelConfig
 from repro.models.params import ParamDef, init as init_params
@@ -58,6 +67,31 @@ def _mamba_block_defs(cfg: ModelConfig) -> dict:
     return {"norm": layers.rmsnorm_defs(cfg.d_model), "mixer": ssm.mamba_defs(cfg)}
 
 
+def _block_defs(cfg: ModelConfig, kind: str) -> dict:
+    """One pattern slot's own parameters: its mixer, then its FFN."""
+    defs = (_mamba_block_defs(cfg) if kind == "mamba" else
+            {"norm": layers.rmsnorm_defs(cfg.d_model),
+             "attn": layers.attention_defs(cfg)})
+    if cfg.ffn == "moe_shared":
+        defs.update(ffn_norm=layers.rmsnorm_defs(cfg.d_model),
+                    moe=moe.moe_defs(cfg), shared=layers.mlp_defs(cfg))
+    return defs
+
+
+def _branch(h: jax.Array, y: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The residual update ``h + residual_multiplier · y``."""
+    return h + cfg.residual_multiplier * y
+
+
+def _shared_expert(p, x: jax.Array) -> jax.Array:
+    """Shared SwiGLU expert, f32 accumulation.  x: (B, S, d) → f32."""
+    h = jnp.einsum("bsd,dcf->bscf", x, p["wi"],
+                   preferred_element_type=jnp.float32)
+    act = (jax.nn.silu(h[..., 0, :]) * h[..., 1, :]).astype(x.dtype)
+    return jnp.einsum("bsf,fd->bsd", act, p["wo"],
+                      preferred_element_type=jnp.float32)
+
+
 @dataclasses.dataclass
 class HybridLM:
     cfg: ModelConfig
@@ -74,8 +108,9 @@ class HybridLM:
             lambda d: ParamDef((cfg.n_repeats, *d.shape), ("layer", *d.axes),
                                dtype=d.dtype, init=d.init, scale=d.scale),
             tree, is_leaf=lambda x: isinstance(x, ParamDef))
-        blocks = {f"b{i}": stack(_mamba_block_defs(cfg))
-                  for i, kind in enumerate(cfg.pattern) if kind == "mamba"}
+        blocks = {f"b{i}": stack(_block_defs(cfg, kind))
+                  for i, kind in enumerate(cfg.pattern)
+                  if kind in ("mamba", "attention")}
         defs = {
             "embed": layers.embed_defs(cfg),
             "blocks": blocks,
@@ -131,6 +166,62 @@ class HybridLM:
         h, _ = scan_blocks(body, h, params["blocks"], self.unroll)
         self._last_aux = jnp.float32(0.0)
         return layers.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+
+    def features(self, params: Params, tokens: jax.Array, pos: jax.Array,
+                 attn_span: int, every_layer: bool = False) -> tuple:
+        """The residual stream after the last layer, with no final norm,
+        over packed runs: the feature a brain encoder reads.
+
+        tokens, pos: (B, S) token ids and each token's position within its
+        run; runs start on SSD chunk boundaries and span at most
+        ``attn_span`` chunks.  Every layer is followed by the MoE and the
+        shared expert (``ffn == "moe_shared"``).  Matmul inputs are
+        ``param_dtype``; the residual stream, norms, router logits, softmax
+        and SSD state are f32 (the router reads the f32 normed input).
+        Returns (h (B, S, d) f32, held-expert pair counts (n_repeats, moe
+        layers per repeat, held)); with ``every_layer`` also each layer's
+        input residual stream (n_layers, B, S, d), the first being the
+        scaled embedding, and its router's picks (n_layers, B, S, top_k),
+        as a model's hidden states of every layer are read."""
+        cfg = self.cfg
+        assert cfg.ffn == "moe_shared", cfg.ffn
+        act = cfg.param_dtype
+        Q = cfg.ssm.chunk
+        with jax.named_scope("backbone"):
+            h = (jnp.take(params["embed"]["tok"], tokens, axis=0)
+                 .astype(jnp.float32) * cfg.embedding_multiplier)
+
+            def body(hh, lp):
+                counts, ins, picks = [], [], []
+                for i, kind in enumerate(cfg.pattern):
+                    blk = lp[f"b{i}"]
+                    ins.append(hh)
+                    u = layers.rmsnorm(blk["norm"], hh, cfg.norm_eps).astype(act)
+                    if kind == "mamba":
+                        y = ssm.mamba_runs(blk["mixer"], cfg, u, pos)
+                    else:
+                        y = layers.run_attention(blk["attn"], cfg, u, pos, Q,
+                                                 attn_span)
+                    hh = _branch(hh, y, cfg)
+                    u = layers.rmsnorm(blk["ffn_norm"], hh, cfg.norm_eps)
+                    with jax.named_scope("moe_routed"):
+                        r, c, idx = moe.moe_dropless(
+                            blk["moe"], cfg, u.reshape(-1, cfg.d_model))
+                    with jax.named_scope("shared_mlp"):
+                        f = _shared_expert(blk["shared"], u.astype(act))
+                    hh = _branch(hh, r.reshape(hh.shape) + f, cfg)
+                    counts.append(c)
+                    picks.append(idx.reshape(*hh.shape[:-1], -1))
+                out = {"counts": jnp.stack(counts)}
+                if every_layer:
+                    out.update(ins=jnp.stack(ins), picks=jnp.stack(picks))
+                return hh, out
+
+            h, out = scan_blocks(body, h, params["blocks"], self.unroll)
+        if not every_layer:
+            return h, out["counts"]
+        flat = lambda a: a.reshape(-1, *a.shape[2:])  # noqa: E731
+        return h, out["counts"], flat(out["ins"]), flat(out["picks"])
 
     def forward(self, params, batch):
         h = self.hidden_states(params, batch)

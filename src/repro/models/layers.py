@@ -364,3 +364,63 @@ def unembed(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
         logits = jnp.einsum("bsd,dv->bsv", x, p["out"],
                             preferred_element_type=jnp.float32)
     return _softcap(logits, cfg.final_logit_softcap)
+
+
+# ---------------------------------------------------------------------------
+# Attention over packed runs (the feature-extractor path)
+# ---------------------------------------------------------------------------
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def run_attention(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
+                  block: int, span: int) -> jax.Array:
+    """Causal GQA within each run of a packed sequence, with no position
+    encoding (Granite-4.0-H's NoPE attention).
+
+    x: (B, S, d) activations; pos: (B, S) position within the run.  Runs
+    are contiguous and start on ``block`` boundaries, and none is longer
+    than ``span`` blocks, so query block i attends to keys in blocks
+    i − span + 1 … i, masked to its own run and to the past.  Q, K and V
+    are cast to the activations' dtype for the scores (f32 accumulation),
+    scaled by ``cfg.attention_multiplier``; the softmax and the
+    probabilities' product with V are f32 (``HIGHEST``).  → (B, S, d) f32.
+    """
+    B, S, _ = x.shape
+    Hq, Hk, D = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    G = Hq // Hk
+    f32, dt = jnp.float32, x.dtype
+    scale = cfg.attention_multiplier
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"], preferred_element_type=f32)
+    k = jnp.einsum("bsd,dnk->bsnk", x, p["wk"], preferred_element_type=f32)
+    v = jnp.einsum("bsd,dnk->bsnk", x, p["wv"], preferred_element_type=f32)
+    q, k = q.astype(dt), k.astype(dt)
+    v = v.astype(dt).astype(f32)
+    nb, W = S // block, span * block
+    lead = ((0, 0), (W - block, 0), (0, 0), (0, 0))
+    kp, vp = jnp.pad(k, lead), jnp.pad(v, lead)
+    idx = jnp.arange(S, dtype=jnp.int32)
+    start = idx[None, :] - pos                                   # run start
+
+    def one_block(i):
+        lo = i * block
+        qb = jax.lax.dynamic_slice_in_dim(q, lo, block, 1)
+        qb = qb.reshape(B, block, Hk, G, D)
+        kb = jax.lax.dynamic_slice_in_dim(kp, lo, W, 1)          # keys lo-W+b…
+        vb = jax.lax.dynamic_slice_in_dim(vp, lo, W, 1)
+        s = jnp.einsum("bqngk,btnk->bngqt", qb, kb,
+                       preferred_element_type=f32) * scale
+        key = lo + block - W + jnp.arange(W, dtype=jnp.int32)     # (W,)
+        qi = jax.lax.dynamic_slice_in_dim(idx, lo, block)         # (block,)
+        st = jax.lax.dynamic_slice_in_dim(start, lo, block, 1)    # (B, block)
+        ok = ((key[None, None, :] <= qi[None, :, None])
+              & (key[None, None, :] >= st[:, :, None]))           # (B,q,t)
+        s = jnp.where(ok[:, None, None], s, -jnp.inf)
+        probs = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bngqt,btnk->bqngk", probs, vb, precision=HIGHEST)
+        return o.reshape(B, block, Hq, D)
+
+    with jax.named_scope("attention"):
+        out = jax.lax.map(one_block, jnp.arange(nb, dtype=jnp.int32))
+    out = out.swapaxes(0, 1).reshape(B, S, Hq, D).astype(dt)
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"], preferred_element_type=f32)

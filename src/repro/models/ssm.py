@@ -95,11 +95,21 @@ def _causal_conv(p, xbc: jax.Array, kernel: int) -> jax.Array:
     return jax.nn.silu(out + p["conv_b"][None, None, :])
 
 
-def _gated_norm(p, y: jax.Array, z: jax.Array, eps: float) -> jax.Array:
-    """Mamba2 gated RMSNorm: norm(y · silu(z)) with per-(head, dim) scale."""
+def _gated_norm(p, y: jax.Array, z: jax.Array, cfg: ModelConfig
+                ) -> jax.Array:
+    """Mamba2 gated RMSNorm: norm(y · silu(z)) with per-(head, dim) scale,
+    normalised over each head (``ssm.norm == "head"``) or over each
+    group's ``d_inner / n_groups`` values (``"group"``).  y: (..., H, P)."""
     g = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32)))
-    var = jnp.mean(g * g, axis=-1, keepdims=True)
-    return (g * jax.lax.rsqrt(var + eps) * p["norm"]).astype(y.dtype)
+    if cfg.ssm.norm == "group":
+        G = cfg.ssm.n_groups
+        gg = g.reshape(*g.shape[:-2], G, -1)
+        var = jnp.mean(gg * gg, axis=-1, keepdims=True)
+        normed = (gg * jax.lax.rsqrt(var + cfg.norm_eps)).reshape(g.shape)
+    else:
+        var = jnp.mean(g * g, axis=-1, keepdims=True)
+        normed = g * jax.lax.rsqrt(var + cfg.norm_eps)
+    return (normed * p["norm"]).astype(y.dtype)
 
 
 def mamba_apply(p, cfg: ModelConfig, u: jax.Array,
@@ -174,7 +184,7 @@ def mamba_apply(p, cfg: ModelConfig, u: jax.Array,
 
     y = (y_intra + y_inter).reshape(B_, S, H, Pd)
     y = y + p["D"][None, None, :, None] * x.astype(jnp.float32)
-    y = _gated_norm(p, y, z, cfg.norm_eps)
+    y = _gated_norm(p, y, z, cfg)
     out = jnp.einsum("bshp,hpd->bsd", y.astype(u.dtype), p["wo"])
     if not return_cache:
         return out
@@ -208,6 +218,123 @@ def mamba_decode(p, cfg: ModelConfig, u: jax.Array, cache: dict
         jnp.einsum("bhn,bhp->bhnp", Bh.astype(jnp.float32), xd)
     y = jnp.einsum("bhn,bhnp->bhp", Ch.astype(jnp.float32), state)
     y = y + p["D"][None, :, None] * x[:, 0].astype(jnp.float32)
-    y = _gated_norm(p, y[:, None], z, cfg.norm_eps)
+    y = _gated_norm(p, y[:, None], z, cfg)
     out = jnp.einsum("bshp,hpd->bsd", y.astype(u.dtype), p["wo"])
     return out, {"state": state, "conv": new_conv}
+
+
+# -- packed runs, f32 SSD (the feature-extractor path) -------------------------
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _causal_conv_runs(p, xbc: jax.Array, pos: jax.Array, kernel: int
+                      ) -> jax.Array:
+    """Depthwise causal conv over time in f32 that never reaches back past
+    the start of a run.  xbc: (B, S, C) f32; pos: (B, S) position of each
+    token within its run."""
+    S = xbc.shape[1]
+    pad = jnp.pad(xbc, ((0, 0), (kernel - 1, 0), (0, 0)))
+    w = p["conv_w"].astype(jnp.float32)
+    out = jnp.zeros_like(xbc)
+    for i in range(kernel):
+        back = kernel - 1 - i
+        tap = pad[:, i:i + S, :] * w[i][None, None, :]
+        out = out + jnp.where((pos >= back)[..., None], tap, 0.0)
+    return jax.nn.silu(out + p["conv_b"].astype(jnp.float32)[None, None, :])
+
+
+def ssd_runs(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+             Cm: jax.Array, reset: jax.Array, chunk: int) -> jax.Array:
+    """Chunked SSD in f32, every contraction at ``HIGHEST``, with the state
+    zeroed before each chunk that starts a run (runs are packed along the
+    sequence, each starting on a chunk boundary).
+
+    x: (B, S, H, P); dt: (B, S, H) post-softplus; A: (H,) < 0;
+    Bm, Cm: (B, S, G, N); reset: (B, S // chunk) bool.  → y (B, S, H, P)
+    without the ``D·x`` skip.  The within-chunk term is formed one chunk at
+    a time, so its (Q, Q, H) decay never exists for more than one chunk.
+    """
+    B_, S, H, Pd = x.shape
+    G, N = Bm.shape[2], Bm.shape[3]
+    Q, hpg = chunk, H // G
+    nc = S // Q
+    xc = (x * dt[..., None]).reshape(B_, nc, Q, H, Pd)
+    Bc = Bm.reshape(B_, nc, Q, G, N)
+    Cc = Cm.reshape(B_, nc, Q, G, N)
+    La = jnp.cumsum((dt * A[None, None, :]).reshape(B_, nc, Q, H), axis=2)
+    tril = jnp.tril(jnp.ones((Q, Q), bool))[None, :, :, None]
+
+    def intra(args):
+        xq, bq, cq, la = args                 # (B,Q,H,P) (B,Q,G,N) (B,Q,H)
+        scores = jnp.einsum("bqgn,bkgn->bqkg", cq, bq, precision=HIGHEST)
+        decay = jnp.exp(jnp.where(tril, la[:, :, None, :] - la[:, None, :, :],
+                                  -jnp.inf))                      # (B,Q,K,H)
+        w = (decay.reshape(B_, Q, Q, G, hpg)
+             * scores[..., None]).reshape(B_, Q, Q, H)
+        return jnp.einsum("bqkh,bkhp->bqhp", w, xq, precision=HIGHEST)
+
+    y_intra = jax.lax.map(intra, (xc.swapaxes(0, 1), Bc.swapaxes(0, 1),
+                                  Cc.swapaxes(0, 1), La.swapaxes(0, 1)))
+    y_intra = y_intra.swapaxes(0, 1)                              # (B,nc,Q,H,P)
+
+    # Each chunk's own contribution to the state at its end, and the
+    # carried state before each chunk (zero where a run starts).
+    seg = jnp.exp(La[:, :, -1:, :] - La)                          # (B,nc,Q,H)
+    sx = (seg[..., None] * xc).reshape(B_, nc, Q, G, hpg, Pd)
+    S_local = jnp.einsum("bcqgn,bcqgjp->bcgjnp", Bc, sx, precision=HIGHEST)
+    chunk_decay = jnp.exp(La[:, :, -1, :]).reshape(B_, nc, G, hpg)
+
+    def scan_body(carry, inp):
+        s_loc, dec, rst = inp
+        prev = jnp.where(rst[:, None, None, None, None], 0.0, carry)
+        return prev * dec[..., None, None] + s_loc, prev
+
+    init = jnp.zeros((B_, G, hpg, N, Pd), jnp.float32)
+    _, S_prev = jax.lax.scan(scan_body, init,
+                             (S_local.swapaxes(0, 1),
+                              chunk_decay.swapaxes(0, 1), reset.swapaxes(0, 1)))
+    S_prev = S_prev.swapaxes(0, 1)                                # (B,nc,G,j,N,P)
+    y_inter = jnp.einsum("bcqgn,bcgjnp->bcqgjp", Cc, S_prev,
+                         precision=HIGHEST).reshape(B_, nc, Q, H, Pd)
+    y_inter = y_inter * jnp.exp(La)[..., None]
+    return (y_intra + y_inter).reshape(B_, S, H, Pd)
+
+
+def mamba_runs(p, cfg: ModelConfig, u: jax.Array, pos: jax.Array
+               ) -> jax.Array:
+    """Mamba-2 mixer over packed runs.  u: (B, S, d) activations (the
+    matmul inputs' dtype); pos: (B, S) position within the run, each run
+    starting on an SSD chunk boundary.  → (B, S, d) f32.
+
+    Projections take the activations as they are and accumulate in f32;
+    the conv, Δ, the SSD state and the gated norm stay in f32; the norm's
+    output is cast back to the activations' dtype for ``out_proj``.
+    """
+    s_cfg = cfg.ssm
+    d_inner, H, Pd, G, N = _dims(cfg)
+    B_, S, _ = u.shape
+    Q = s_cfg.chunk
+    assert S % Q == 0, (S, Q)
+    f32 = jnp.float32
+    with jax.named_scope("mamba_in_proj"):
+        x = jnp.einsum("bsd,dhp->bshp", u, p["wx"],
+                       preferred_element_type=f32).reshape(B_, S, H * Pd)
+        Bm = jnp.einsum("bsd,dgn->bsgn", u, p["wB"],
+                        preferred_element_type=f32).reshape(B_, S, G * N)
+        Cm = jnp.einsum("bsd,dgn->bsgn", u, p["wC"],
+                        preferred_element_type=f32).reshape(B_, S, G * N)
+        z = jnp.einsum("bsd,dhp->bshp", u, p["wz"],
+                       preferred_element_type=f32)
+        dt = jnp.einsum("bsd,dh->bsh", u, p["wdt"],
+                        preferred_element_type=f32)
+    xbc = _causal_conv_runs(p, jnp.concatenate([x, Bm, Cm], axis=-1), pos,
+                            s_cfg.conv_kernel)
+    x, Bm, Cm = _split_xbc(cfg, xbc)
+    dt = jax.nn.softplus(dt + p["dt_bias"])
+    A = -jnp.exp(p["A_log"])
+    with jax.named_scope("ssd"):
+        y = ssd_runs(x, dt, A, Bm, Cm, pos[:, ::Q] == 0, Q)
+    y = y + p["D"][None, None, :, None] * x
+    y = _gated_norm(p, y, z, cfg).astype(u.dtype)
+    return jnp.einsum("bshp,hpd->bsd", y, p["wo"], preferred_element_type=f32)
