@@ -40,11 +40,13 @@ per-λ path ``bands`` and ``factor_update_flops`` which band plan),
 ``prefetch.close``,
 ``wholebrain.block`` / ``wholebrain.xstats``, ``serve.wave.build`` /
 ``serve.wave.h2d`` / ``serve.wave.execute`` / ``serve.wave.sums_wait`` /
-``serve.copyback`` (each carrying its ``wave`` number) and
-``serve.results``, ``registry.load`` / ``registry.evict`` /
-``registry.hit``, ``fleet.reject`` / ``fleet.flush`` (with its ``flush``
-number).  A span that times device work forces its outputs only while a
-tracer is installed, so tracing off leaves the schedule untouched.
+``serve.copyback`` (each carrying its ``wave`` number; ``serve.wave.build``
+also ``reused``, 1 where the wave's host buffers were an earlier wave's,
+and ``zeroed_rows``, the target rows it zeroed) and ``serve.results``,
+``registry.load`` / ``registry.evict`` / ``registry.hit``,
+``fleet.reject`` / ``fleet.flush`` (with its ``flush`` number).  A span
+that times device work forces its outputs only while a tracer is
+installed, so tracing off leaves the schedule untouched.
 
 Metrics-snapshot schema (``obs.snapshot()``; version ``repro.obs/v1``)
 ----------------------------------------------------------------------
@@ -83,6 +85,10 @@ instrument                            type       producer
 ``d2h_bytes{site=wave}``              counter    prediction bytes copied back
                                                  per wave; also the ``bytes``
                                                  of ``serve.copyback``
+``wave_staging{outcome=...}``         counter    serving waves built in host buffers
+                                                 an earlier wave had filled
+                                                 (``reused``) or allocated for
+                                                 this one (``allocated``)
 ``registry_hits`` / ``registry_loads``  counter  bundle cache hits / cold loads
 ``registry_evictions``                counter    LRU + fault evictions
 ``admitted_rows`` / ``rejected_requests``  counter  fleet admission outcomes

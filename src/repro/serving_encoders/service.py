@@ -38,6 +38,17 @@ each request alone (the replay harness gates this):
   (A lane-parallel ``jnp.sum`` would regroup the chain by absolute row
   position and break this.)
 
+**Staging.**  Each wave is built on the host in a set of buffers the
+service keeps (``_StagingPool``: two sets per wave shape, taken in turn)
+rather than in fresh arrays, which at whole-brain widths cost a page fault
+per page on every wave.  The build writes every byte of the set — the
+requests' rows, and zeros over the pad rows, the unscored rows' targets,
+the one-hot and the unclaimed sum slots — so the program receives exactly
+the bits fresh zero arrays would give, and both properties above hold.
+A set is refilled only once the wave last built in it has finished: the
+transfer in may still read the buffer after it returns (on the CPU the
+device array IS the buffer).
+
 Wave shapes come from ``wave_buckets`` (2–3 ladder sizes, each compiled
 once, picked per wave by the rows remaining — mixed small/large traffic
 stops paying the big shape's pad fraction) or the single ``wave_rows``;
@@ -54,6 +65,7 @@ bad client cannot waste another tenant's completed device work.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import threading
 from typing import Callable, Sequence
@@ -229,6 +241,101 @@ def plan_mixed_waves(req_rows: Sequence[int], scored: Sequence[bool],
     return waves
 
 
+class _StagingSet:
+    """One wave's host inputs ``(X, Yt, onehot, sums_in)`` and ``fence``,
+    the device arrays of the last wave built in them (None: none pending);
+    ``busy`` while a builder holds it."""
+
+    __slots__ = ("arrays", "fence", "busy")
+
+    def __init__(self, rows: int, p: int, t: int, slots: int):
+        # Empty, not zeros: the first build writes every byte anyway.
+        self.arrays = (np.empty((rows, p), np.float32),
+                       np.empty((rows, t), np.float32),
+                       np.empty((rows, slots), np.float32),
+                       np.empty((slots, 5, t), np.float32))
+        self.fence = None
+        self.busy = True
+
+    def wait(self) -> None:
+        """Block until the wave last built here no longer needs it."""
+        import jax
+
+        if self.fence is not None and not all(
+                a.is_ready() for a in self.fence):
+            jax.block_until_ready(self.fence)
+        self.fence = None
+
+
+class _StagingPool:
+    """The host buffers waves are built in: per key ``(wave rows, p, t,
+    slots)`` two sets, taken in turn so one can be filled while the wave
+    built in the other is in flight; beyond ``max_keys`` keys the least
+    recently used is dropped.  A set is held by one builder at a time; a
+    builder that finds both held (concurrent ``serve`` calls) gets a set
+    of its own that is not kept."""
+
+    def __init__(self, max_keys: int):
+        self.max_keys = max_keys
+        self._lock = threading.Lock()
+        self._keys: collections.OrderedDict = collections.OrderedDict()
+
+    def __len__(self) -> int:
+        return len(self._keys)
+
+    def take(self, key: tuple) -> tuple[_StagingSet, bool]:
+        """A set for ``key``, held until :meth:`release`; and whether it
+        was reused (else allocated now)."""
+        with self._lock:
+            entry = self._keys.pop(key, None) or {"sets": [], "turn": 0}
+            self._keys[key] = entry
+            while len(self._keys) > self.max_keys:
+                self._keys.popitem(last=False)
+            for _ in range(2):
+                i = entry["turn"]
+                entry["turn"] ^= 1
+                if i == len(entry["sets"]):
+                    entry["sets"].append(_StagingSet(*key))
+                    return entry["sets"][i], False
+                s = entry["sets"][i]
+                if not s.busy:
+                    s.busy = True
+                    return s, True
+        return _StagingSet(*key), False
+
+    def release(self, staging: _StagingSet, fence) -> None:
+        with self._lock:
+            staging.fence = fence
+            staging.busy = False
+
+
+def _stage(arrays: tuple, wave: MixedWave, blocks: list, targets: list,
+           req_sums: dict) -> tuple[int, bool]:
+    """Write ``wave``'s inputs over whatever ``arrays`` held: the segments'
+    rows, targets, one-hot and carried sums, and zeros everywhere else.
+    Returns the target rows zeroed (pad plus unscored rows) and whether
+    any segment is scored."""
+    X, Yt, onehot, sums_in = arrays
+    onehot.fill(0.0)
+    claimed = np.zeros(sums_in.shape[0], bool)
+    zeroed = wave.rows - wave.fill
+    for seg in wave.segments:
+        dst = slice(seg.wave_lo, seg.wave_lo + seg.req_hi - seg.req_lo)
+        X[dst] = blocks[seg.req][seg.req_lo:seg.req_hi]
+        if seg.slot is None:
+            Yt[dst] = 0.0
+            zeroed += seg.req_hi - seg.req_lo
+        else:
+            Yt[dst] = targets[seg.req][seg.req_lo:seg.req_hi]
+            onehot[dst, seg.slot] = 1.0
+            sums_in[seg.slot] = req_sums[seg.req]
+            claimed[seg.slot] = True
+    X[wave.fill:] = 0.0
+    Yt[wave.fill:] = 0.0
+    sums_in[~claimed] = 0.0
+    return zeroed, bool(claimed.any())
+
+
 class EncoderService:
     """Micro-batching mixed-wave server over an ``EncoderRegistry``.
 
@@ -279,6 +386,10 @@ class EncoderService:
         self._seen_shapes: set = set()
         self._waves_flown = 0        # numbers each mixed wave's spans
         self.stats = ServiceStats()
+        # Two keys a bucket: one (p, t) shape at a time per bucket, with
+        # room for a second while the traffic moves between two.
+        self._staging = _StagingPool(
+            2 * len(wave_buckets) if wave_buckets else 2)
 
         def _predict(X, W, mu_x, sd_x, mu_y, sd_y):
             # Python side effect at TRACE time: runs once per distinct
@@ -480,41 +591,45 @@ class EncoderService:
         for wave in plan:
             seq = self._waves_flown
             self._waves_flown += 1
-            with obs.span("serve.wave.build", rows=wave.rows,
-                          fill=wave.fill, model=model, wave=seq):
-                X = np.zeros((wave.rows, p), np.float32)
-                Yt = np.zeros((wave.rows, t), np.float32)
-                onehot = np.zeros((wave.rows, s), np.float32)
-                sums_in = np.zeros((s, 5, t), np.float32)
-                has_scored = False
-                for seg in wave.segments:
-                    dst = slice(seg.wave_lo,
-                                seg.wave_lo + seg.req_hi - seg.req_lo)
-                    X[dst] = blocks[seg.req][seg.req_lo:seg.req_hi]
-                    if seg.slot is not None:
-                        has_scored = True
-                        Yt[dst] = targets[seg.req][seg.req_lo:seg.req_hi]
-                        onehot[dst, seg.slot] = 1.0
-                        sums_in[seg.slot] = req_sums[seg.req]
-            # Under a tracer each span below waits for its device work, so
-            # it times the copy or the program rather than its enqueue.
-            nbytes = X.nbytes + Yt.nbytes + onehot.nbytes + sums_in.nbytes
-            with obs.transfer("serve.wave.h2d", "h2d_bytes", nbytes,
-                              site="wave", rows=wave.rows, fill=wave.fill,
-                              wave=seq):
-                inputs = (jnp.asarray(X), jnp.asarray(Yt),
-                          jnp.asarray(onehot), jnp.asarray(sums_in))
-                if traced:
-                    jax.block_until_ready(inputs)
-            with obs.span("serve.wave.execute", rows=wave.rows,
-                          fill=wave.fill, model=model, wave=seq):
-                with self._expect_shape(("mixed", wave.rows, p, t, s)):
-                    P, sums_out = self._predict_mixed(*inputs, *enc_args)
-                self.stats.record_wave(wave.rows, wave.fill)
+            staging, reused = self._staging.take((wave.rows, p, t, s))
+            obs.get_metrics().counter(
+                "wave_staging",
+                outcome="reused" if reused else "allocated").inc()
+            # What must finish before the set is refilled: the transfer
+            # until the program is enqueued, then the program.
+            fence = None
+            try:
+                with obs.span("serve.wave.build", rows=wave.rows,
+                              fill=wave.fill, model=model, wave=seq,
+                              reused=int(reused)) as span:
+                    staging.wait()
+                    zeroed, has_scored = _stage(
+                        staging.arrays, wave, blocks, targets, req_sums)
+                    span.set(zeroed_rows=zeroed)
+                # Under a tracer each span below waits for its device work,
+                # so it times the copy or the program rather than its
+                # enqueue.
+                nbytes = sum(a.nbytes for a in staging.arrays)
+                with obs.transfer("serve.wave.h2d", "h2d_bytes", nbytes,
+                                  site="wave", rows=wave.rows,
+                                  fill=wave.fill, wave=seq):
+                    inputs = fence = tuple(
+                        jnp.asarray(a) for a in staging.arrays)
+                    if traced:
+                        jax.block_until_ready(inputs)
+                with obs.span("serve.wave.execute", rows=wave.rows,
+                              fill=wave.fill, model=model, wave=seq):
+                    with self._expect_shape(("mixed", wave.rows, p, t, s)):
+                        P, sums_out = fence = self._predict_mixed(
+                            *inputs, *enc_args)
+                    self.stats.record_wave(wave.rows, wave.fill)
+            finally:
+                self._staging.release(staging, fence)
             if has_scored:
                 # The chain is a data dependency: the slot carries must
                 # land on host before the request's NEXT wave is built.
-                # Unscored waves stay fully async-enqueued.
+                # Unscored waves stay async-enqueued, two a wave shape
+                # (its staging sets) in flight at once.
                 with obs.span("serve.wave.sums_wait", rows=wave.rows,
                               wave=seq):
                     host_sums = np.asarray(sums_out)
